@@ -17,9 +17,9 @@ from repro.data.synthetic import SynthConfig, make_dataset
 
 SMOKE = os.environ.get("BENCH_SMOKE") == "1"
 
-# CPU-CI scale factors; the generators scale to the paper's full sizes
-# (HEPTH 58,515 refs / DBLP 50,195 / DBLP-BIG 4.6M) with scale=1.0 and
-# scale~90 respectively.
+# CPU-CI scale factors.  Scale 1 gives about 1,842 HEPTH references and
+# 1,704 DBLP references, so the paper's full sizes (HEPTH 58,515 refs /
+# DBLP 50,195 / DBLP-BIG 4.6M) are about scale 31.4, 29.4 and 2,700.
 _DEFAULT_SCALE = "0.03" if SMOKE else "0.12"
 HEPTH_SCALE = float(os.environ.get("BENCH_HEPTH_SCALE", _DEFAULT_SCALE))
 DBLP_SCALE = float(os.environ.get("BENCH_DBLP_SCALE", _DEFAULT_SCALE))

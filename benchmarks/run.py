@@ -82,6 +82,9 @@ def main() -> None:
     unknown = want - {name for name, _ in MODULES}
     if unknown:
         raise SystemExit(f"unknown benchmark(s): {sorted(unknown)}")
+    from repro.kernels.common import use_compile_cache
+
+    use_compile_cache()
     failures: list[str] = []
     for name, desc in MODULES:
         if want and name not in want:

@@ -19,10 +19,7 @@ Wall-clock scaling on one box is bounded by the physical core count —
 N co-scheduled replicas on fewer than N cores timeshare — so the JSON
 records ``cpu_count`` and ``check_bench --gate=shard`` only enforces
 the 2-shard efficiency floor where two shards could actually run in
-parallel.  Shard counts whose mesh cannot form on this jax build (no
-CPU collectives client) fall back to single-process multi-device
-sharding (``--xla_force_host_platform_device_count``), recorded as
-``mode: multidevice`` — digests must still match.
+parallel.
 """
 
 from __future__ import annotations
@@ -114,69 +111,14 @@ def _run_multiprocess(n_shards: int) -> list[dict]:
     return _collect(procs)
 
 
-def _run_multidevice(n_shards: int) -> list[dict]:
-    """Fallback when the jax build has no CPU collectives client: one
-    process, ``n_shards`` forced host devices, bin rows still sharded."""
-    env = _base_env()
-    env["XLA_FLAGS"] = (
-        f"--xla_force_host_platform_device_count={n_shards} "
-        + env.get("XLA_FLAGS", "")
-    )
-    proc = subprocess.Popen(
-        [sys.executable, _WORKER],
-        stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True, env=env,
-    )
-    return _collect([proc])
-
-
-def _mesh_available() -> bool:
-    """Probe a 2-process mesh once (gloo is not in every jax build)."""
-    procs = []
-    try:
-        coord = f"127.0.0.1:{_free_port()}"
-        for i in range(2):
-            env = _base_env()
-            env["REPRO_SHARD_COORD"] = coord
-            env["REPRO_SHARD_N"] = "2"
-            env["REPRO_SHARD_ID"] = str(i)
-            procs.append(
-                subprocess.Popen(
-                    [sys.executable, "-c",
-                     "from repro.stream.shard import ShardContext\n"
-                     "ctx = ShardContext.create()\n"
-                     "assert ctx.merger.union({ctx.shard_id}) == {0, 1}\n"],
-                    stdout=subprocess.PIPE, stderr=subprocess.PIPE,
-                    text=True, env=env,
-                )
-            )
-        ok = True
-        for p in procs:
-            p.communicate(timeout=300)
-            ok = ok and p.returncode == 0
-        return ok
-    except Exception:
-        for p in procs:
-            if p.poll() is None:
-                p.kill()
-        return False
-
-
 def main() -> dict:
-    mesh_ok = _mesh_available()
-    if not mesh_ok:
-        print("no multi-process CPU mesh on this jax build; "
-              "falling back to multi-device sharding")
     shards = []
     row("n_shards", "mode", "refs", "ingest_s", "refs_per_s",
         "resolve_qps", "agree")
     for n in SHARD_COUNTS:
         t0 = time.perf_counter()
-        if n == 1 or mesh_ok:
-            workers = _run_multiprocess(n)
-            mode = "multiprocess" if n > 1 else "single"
-        else:
-            workers = _run_multidevice(n)
-            mode = "multidevice"
+        workers = _run_multiprocess(n)
+        mode = "multiprocess" if n > 1 else "single"
         wall = time.perf_counter() - t0
         digests = {w["digest"] for w in workers}
         if len(digests) != 1:

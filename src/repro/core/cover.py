@@ -30,6 +30,7 @@ from __future__ import annotations
 
 import dataclasses
 
+import jax.numpy as jnp
 import numpy as np
 
 from repro.core import pairs as pairlib
@@ -74,28 +75,25 @@ def build_canopies(
     features: np.ndarray,
     t_loose: float,
     t_tight: float,
-    *,
-    chunk: int = 1024,
 ) -> list[np.ndarray]:
     """Deterministic canopy construction (seeds in id order).
 
     The paper picks random seeds; a fixed seed order is a valid draw and
     keeps the construction reproducible.  Order-invariance of the *match
     output* is the framework's consistency property, tested separately.
+
+    The pool is uploaded once and each seed is probed against all of it
+    in one kernel call: per-seed host slices of the pool would move the
+    whole corpus to the device once per seed.
     """
     n = features.shape[0]
     remaining = np.ones(n, dtype=bool)
     canopies: list[np.ndarray] = []
-    order = np.arange(n)
-    for seed in order:
+    pool = jnp.asarray(features)
+    for seed in range(n):
         if not remaining[seed]:
             continue
-        sims = np.zeros(n, dtype=np.float32)
-        q = features[seed : seed + 1]
-        for lo in range(0, n, chunk):
-            hi = min(lo + chunk, n)
-            block = np.asarray(sim_ops.sim_above(q, features[lo:hi], 0.0))[0]
-            sims[lo:hi] = block
+        sims = np.asarray(sim_ops.sim_above(features[seed : seed + 1], pool, 0.0))[0]
         members = np.where(sims >= t_loose)[0]
         if len(members) == 0:
             members = np.array([seed])

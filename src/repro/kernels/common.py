@@ -1,67 +1,62 @@
 """Shared helpers for the Pallas TPU kernels.
 
-Routing policy (``ops.py`` of every kernel):
+Routing policy (``ops.py`` of every kernel), decided by
+:func:`pallas_mode` from ``jax.default_backend()``:
 
-* On TPU, run the compiled Pallas kernel.
-* On CPU/GPU, run the pure-jnp reference (identical math) so the whole
-  framework works everywhere.
-* ``REPRO_PALLAS=interpret`` forces the Pallas kernel in interpret mode
-  (kernel body executed in Python) — this is how the CPU CI validates
-  the kernels against the oracles in ``ref.py``.
+* On TPU, run the compiled Pallas kernel — always.  ``REPRO_PALLAS``
+  set to anything but ``compiled`` raises there: a chip run never
+  falls back to the references or the interpreter.
+* Elsewhere, run the pure-jnp reference in ``ref.py`` (identical math)
+  so the whole framework works on CPU.  ``REPRO_PALLAS=interpret``
+  runs the Pallas kernel bodies in interpret mode instead — this is how
+  the CPU tests validate the kernels against the oracles.
 """
 
 from __future__ import annotations
 
 import os
+from pathlib import Path
 
 import jax
 import numpy as np
 
 
-def compiler_params(**kwargs):
-    """Version-compat constructor for Pallas TPU compiler params.
-
-    jax renamed ``pltpu.TPUCompilerParams`` to ``pltpu.CompilerParams``;
-    depending on the installed version exactly one of the two exists.
-    Every kernel builds its params through this helper so the repo works
-    on either side of the rename.
-    """
-    from jax.experimental.pallas import tpu as pltpu
-
-    cls = getattr(pltpu, "CompilerParams", None)
-    if cls is None:
-        cls = pltpu.TPUCompilerParams
-    return cls(**kwargs)
-
-
-def shard_map(fn, mesh, in_specs, out_specs):
-    """Version-compat ``shard_map``: ``jax.shard_map`` (new) falls back to
-    ``jax.experimental.shard_map.shard_map`` (<= 0.4.x), and the disabled
-    replication check is passed under whichever kwarg the version takes
-    (``check_vma`` post-rename, ``check_rep`` before)."""
-    if hasattr(jax, "shard_map"):
-        _shard_map = jax.shard_map
-    else:
-        from jax.experimental.shard_map import shard_map as _shard_map
-    kw = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-    try:
-        return _shard_map(fn, **kw, check_vma=False)
-    except TypeError:
-        return _shard_map(fn, **kw, check_rep=False)
-
-
 def pallas_mode() -> str:
-    """'compiled' | 'interpret' | 'off'."""
+    """'compiled' on TPU; 'interpret' or 'off' (the references) elsewhere."""
     env = os.environ.get("REPRO_PALLAS", "").lower()
+    if jax.default_backend() == "tpu":
+        if env not in ("", "compiled"):
+            raise RuntimeError(
+                f"REPRO_PALLAS={env!r} on a TPU backend: the chip runs the "
+                "compiled kernels only; unset it"
+            )
+        return "compiled"
+    if env in ("", "off"):
+        return "off"
     if env == "interpret":
         return "interpret"
-    if env == "off":
-        return "off"
-    try:
-        platform = jax.default_backend()
-    except Exception:  # pragma: no cover
-        platform = "cpu"
-    return "compiled" if platform == "tpu" else "off"
+    raise ValueError(
+        f"REPRO_PALLAS={env!r}: expected 'interpret' or 'off' off the TPU"
+    )
+
+
+def use_compile_cache() -> str:
+    """Keep JAX's persistent compilation cache in a fixed directory.
+
+    Called by entry points before their first compile, never on import.
+    JAX reads ``JAX_COMPILATION_CACHE_DIR`` itself, so when that is set
+    nothing is changed.  Otherwise the cache goes to
+    ``<checkout>/.jax_cache``: the directory is part of what a cached
+    entry is found by, so it must not depend on a temporary name, a pid
+    or the time.  Returns the directory in use.
+    """
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    checkout = Path(__file__).resolve().parents[3]  # <checkout>/src/repro/kernels
+    path = str(checkout / ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def round_up(x: int, m: int) -> int:
@@ -80,14 +75,17 @@ def pad_axis(x, axis: int, to: int, fill=0.0):
     return jnp.pad(x, pad, constant_values=fill)
 
 
-def pick_tile(n: int, preferred: int = 128, floor: int = 8) -> int:
-    """Largest hardware-aligned tile <= preferred that keeps padding sane."""
-    if n >= preferred:
-        return preferred
-    t = floor
-    while t * 2 <= max(n, floor):
-        t *= 2
-    return max(t, floor)
+def pick_tile(n: int, preferred: int = 128) -> int:
+    """Block length along one array axis of ``n`` elements.
+
+    Mosaic accepts a block only if each of its last two dims is
+    divisible by 8 (sublanes) and 128 (lanes) respectively, or equals
+    the array's dim.  An axis of at most ``preferred`` elements is one
+    whole block, so it needs no padding; a longer axis is cut into
+    ``preferred``-long blocks and padded to a multiple of that.
+    ``preferred`` must be a multiple of 128.
+    """
+    return n if n <= preferred else preferred
 
 
 def assert_allclose(a, b, rtol=1e-5, atol=1e-5, msg=""):
@@ -95,19 +93,17 @@ def assert_allclose(a, b, rtol=1e-5, atol=1e-5, msg=""):
 
 
 # ---------------------------------------------------------------------------
-# Multi-process mesh helpers (CPU-mesh sharded serving)
+# Mesh placement helpers (multi-device and multi-process sharded serving)
 # ---------------------------------------------------------------------------
 
 
 def mesh_spans_processes(mesh) -> bool:
     """True when the mesh covers devices from more than one JAX process.
 
-    On a single-process mesh (the normal case, including
-    ``--xla_force_host_platform_device_count`` multi-device CPU), plain
-    ``jnp.asarray`` uploads are valid global arrays for ``shard_map``.
-    Across processes they are not: every input to a global-mesh
-    computation must be built with an explicit ``NamedSharding`` so all
-    processes agree on the layout.
+    Across processes, every input to a global-mesh computation is built
+    from host memory with an explicit ``NamedSharding`` so all processes
+    agree on the layout, and row outputs the host reads are gathered
+    back to replicated inside the program.
     """
     if mesh is None:
         return False
@@ -117,41 +113,30 @@ def mesh_spans_processes(mesh) -> bool:
         return False
 
 
+def _placeable(x, mesh):
+    """A process-local device array is not part of a global array, so
+    uploads to a mesh spanning processes go from host memory."""
+    return np.asarray(x) if mesh_spans_processes(mesh) else x
+
+
 def put_replicated(x, mesh):
-    """Upload a host array fully replicated over ``mesh``.
-
-    Single-process meshes take the cheap ``jnp.asarray`` path (committed
-    to the default device, exactly what the pre-distributed code did);
-    multi-process meshes need a real replicated ``NamedSharding`` so the
-    array is addressable as one global value on every host.
-    """
-    import jax.numpy as jnp
-
-    if not mesh_spans_processes(mesh):
-        return jnp.asarray(x)
+    """Place an array, host or device, fully replicated on every device
+    of ``mesh``."""
     from jax.sharding import NamedSharding, PartitionSpec
 
-    return jax.device_put(np.asarray(x), NamedSharding(mesh, PartitionSpec()))
+    return jax.device_put(_placeable(x, mesh), NamedSharding(mesh, PartitionSpec()))
 
 
 def put_sharded(x, mesh, axis):
-    """Upload a host array sharded over ``mesh`` along its leading dim.
+    """Place an array, host or device, on ``mesh`` split along its
+    leading dim.
 
     The leading dimension must be divisible by the mesh size (callers
-    pad batches with ``pad_mult``).  Single-process meshes fall back to
-    ``jnp.asarray`` — ``shard_map`` reshards the committed array itself,
-    which is what the existing single-host dispatch relies on.
+    pad batches with ``pad_mult``).  Each device receives only its own
+    rows: an array committed to one device and handed to a ``shard_map``
+    over several would be copied whole to every device instead.
     """
-    import jax.numpy as jnp
-
-    if not mesh_spans_processes(mesh):
-        return jnp.asarray(x)
     from jax.sharding import NamedSharding, PartitionSpec
 
     spec = PartitionSpec(axis, *([None] * (np.ndim(x) - 1)))
-    return jax.device_put(np.asarray(x), NamedSharding(mesh, spec))
-
-
-def host_array(x) -> np.ndarray:
-    """Bring a (replicated) device array back to the host as numpy."""
-    return np.asarray(x)
+    return jax.device_put(_placeable(x, mesh), NamedSharding(mesh, spec))

@@ -27,7 +27,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import compiler_params, pad_axis, pick_tile, round_up
+from repro.kernels.common import pad_axis, pick_tile, round_up
 
 NEG_INF = -1e30
 
@@ -120,7 +120,7 @@ def flash_attention(q, k, v, scale, *, causal: bool = True,
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
         ],
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -7,8 +7,10 @@ into the epilogue, so the sweep never round-trips the (S, P) delta
 through HBM between the matmul and the bias.
 
 Tiling: output tiles (bs, bp) held in a VMEM f32 scratch accumulator;
-the contraction dim is the innermost ("arbitrary") grid axis.  Tiles are
-multiples of (8, 128) to match the VPU/MXU lane layout.
+the contraction dim is the innermost ("arbitrary") grid axis.  Every
+pair axis of an EM bin (P <= 496 for k <= 32) is one whole block, so the
+(P, P) coupling is never padded; longer axes are cut into 128-multiples
+(``common.pick_tile``).
 """
 
 from __future__ import annotations
@@ -20,7 +22,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import compiler_params, pad_axis, pick_tile, round_up
+from repro.kernels.common import pad_axis, pick_tile, round_up
 
 
 def _sweep_kernel(u_ref, x_ref, c_ref, o_ref, acc_ref):
@@ -38,7 +40,7 @@ def _sweep_kernel(u_ref, x_ref, c_ref, o_ref, acc_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "bs", "bp", "bk"))
-def sweep_matrix(u, C, X, *, interpret: bool = False, bs=128, bp=128, bk=128):
+def sweep_matrix(u, C, X, *, interpret: bool = False, bs=128, bp=512, bk=512):
     """u (P,), C (P, P), X (S, P) -> (S, P) f32 via pallas_call."""
     S, P = X.shape
     bs = pick_tile(S, bs)
@@ -63,7 +65,7 @@ def sweep_matrix(u, C, X, *, interpret: bool = False, bs=128, bp=128, bk=128):
         out_specs=pl.BlockSpec((bs, bp), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((Sp, Pp), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bs, bp), jnp.float32)],
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

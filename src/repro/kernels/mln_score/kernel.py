@@ -28,7 +28,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import compiler_params, pad_axis, pick_tile, round_up
+from repro.kernels.common import pad_axis, pick_tile, round_up
 
 
 def _score_kernel(u_ref, x_ref, xj_ref, c_ref, o_ref, y_acc, f_acc):
@@ -54,7 +54,7 @@ def _score_kernel(u_ref, x_ref, xj_ref, c_ref, o_ref, y_acc, f_acc):
     def _epilogue():
         xj = xj_ref[0]  # (bs, bj)
         f_acc[0] += jnp.sum(0.5 * y_acc[0] * xj, axis=1, keepdims=True)
-        f_acc[0] += jnp.dot(xj, u_ref[0].T, preferred_element_type=jnp.float32)
+        f_acc[0] += jnp.sum(xj * u_ref[0], axis=1, keepdims=True)
 
     @pl.when(
         (j == pl.num_programs(2) - 1) & (k == pl.num_programs(3) - 1)
@@ -64,7 +64,7 @@ def _score_kernel(u_ref, x_ref, xj_ref, c_ref, o_ref, y_acc, f_acc):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "bs", "bj", "bk"))
-def score_sets(u, C, X, *, interpret: bool = False, bs=128, bj=128, bk=128):
+def score_sets(u, C, X, *, interpret: bool = False, bs=128, bj=512, bk=512):
     """u (B,P), C (B,P,P), X (B,S,P) -> (B,S) f32."""
     B, S, P = X.shape
     bs = pick_tile(S, bs)
@@ -93,7 +93,7 @@ def score_sets(u, C, X, *, interpret: bool = False, bs=128, bj=128, bk=128):
             pltpu.VMEM((1, bs, bj), jnp.float32),
             pltpu.VMEM((1, bs, 1), jnp.float32),
         ],
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary", "arbitrary"),
         ),
         interpret=interpret,

@@ -73,10 +73,9 @@ def init_em_distributed(
     Returns False — without touching jax — when no coordinator is
     configured, so single-process callers can call this unconditionally.
 
-    On CPU backends the cross-process collective client must be selected
-    *before* ``jax.distributed.initialize``; jaxlib builds that predate
-    the gloo client (or name the option differently) raise, and the
-    caller is expected to skip the distributed path in that case.
+    On CPU backends the cross-process collective client (gloo) must be
+    selected *before* ``jax.distributed.initialize``; the option is
+    ignored by the other backends.
     """
     global _distributed_initialized
     import os
@@ -90,10 +89,7 @@ def init_em_distributed(
         num_processes = int(os.environ.get("REPRO_SHARD_N", "1"))
     if process_id is None:
         process_id = int(os.environ.get("REPRO_SHARD_ID", "0"))
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except Exception:
-        pass  # non-CPU backend or pre-gloo jax: initialize decides
+    jax.config.update("jax_cpu_collectives_implementation", "gloo")
     jax.distributed.initialize(
         coordinator_address=coordinator,
         num_processes=num_processes,

@@ -294,8 +294,6 @@ class ShardMerger:
         """All-gather equal-shape per-process row blocks (process order)."""
         import jax
 
-        from repro.kernels import common as kcommon
-
         mesh = self.mesh
         axis = mesh.axis_names[0]
         devs_here = [
@@ -324,9 +322,10 @@ class ShardMerger:
             import jax.numpy as jnp  # noqa: F401 - jitted body below
 
             fn = self._gather_fns[key] = jax.jit(
-                kcommon.shard_map(
+                jax.shard_map(
                     lambda x: jax.lax.all_gather(x, axis, axis=0, tiled=True),
-                    mesh, (P(axis),), P(),
+                    mesh=mesh, in_specs=(P(axis),), out_specs=P(),
+                    check_vma=False,
                 )
             )
         return np.asarray(fn(garr))

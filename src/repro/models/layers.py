@@ -59,26 +59,10 @@ def mixed_einsum(spec, a, b):
 DP_OVER_MODEL = False
 
 
-
-def ambient_mesh():
-    """Version-compat ambient-mesh lookup: ``jax.sharding.get_abstract_mesh``
-    (new) falls back to the thread-resources physical mesh (jax <= 0.4.x)."""
-    gam = getattr(jax.sharding, "get_abstract_mesh", None)
-    if gam is not None:
-        return gam()
-    try:
-        from jax._src import mesh as mesh_lib
-
-        m = mesh_lib.thread_resources.env.physical_mesh
-    except Exception:
-        return None
-    return None if m.empty else m
-
-
 def _dp_axes():
     """Data-parallel axes of the ambient mesh ('pod' shards batch too)."""
-    am = ambient_mesh()
-    if am is None or am.empty:
+    am = jax.sharding.get_abstract_mesh()
+    if am.empty:
         return None, 1
     names = am.axis_names
     dp_names = ("pod", "data", "model") if DP_OVER_MODEL else ("pod", "data")
@@ -98,7 +82,7 @@ def shard_spec(x, entries):
     axes, _ = _dp_axes()
     if axes is None:
         return x
-    am = ambient_mesh()
+    am = jax.sharding.get_abstract_mesh()
     out = []
     for dim, e in zip(x.shape, entries):
         ee = axes if e == "dp" else e
@@ -141,7 +125,7 @@ def shard_batch(x, batch_dim: int = 0, model_dim: int | None = None):
     axes, n = _dp_axes()
     if axes is None or n == 1 or x.shape[batch_dim] % n != 0:
         return x
-    am = ambient_mesh()
+    am = jax.sharding.get_abstract_mesh()
     msize = am.shape.get("model", 1)
     entries: list = [None] * x.ndim
     entries[batch_dim] = axes if len(axes) > 1 else axes[0]
@@ -368,9 +352,9 @@ def chunked_attention(q, k, v, scale, *, causal=True, q_block: int | None = None
         except (RuntimeError, ValueError):
             return t
 
-    am = ambient_mesh()
+    am = jax.sharding.get_abstract_mesh()
     if (
-        am is not None and not am.empty
+        not am.empty
         and "model" in am.axis_names
         and not DP_OVER_MODEL
         and T % am.shape.get("model", 1) == 0

@@ -3,10 +3,8 @@
 Usage: ``python shard_worker.py <mode> <scheme> <n_batches> <perm_seed>``
 
 * ``mode`` — ``hepth`` (stream a synthetic corpus through a
-  :class:`~repro.stream.shard.ShardCoordinator`), ``lattice`` (drive
-  ``run_parallel`` on the hand-packed evidence lattice), or ``probe``
-  (minimal cross-process collective check, used to gate the distributed
-  leg on jax builds without a CPU collectives client).
+  :class:`~repro.stream.shard.ShardCoordinator`) or ``lattice`` (drive
+  ``run_parallel`` on the hand-packed evidence lattice).
 * ``perm_seed`` — ``-1`` for arrival order; otherwise the seed of a
   batch-order permutation (global ids are preserved via ``ingest(...,
   ids=...)``, so the permuted schedule resolves the same corpus).
@@ -32,15 +30,6 @@ def main() -> None:
     from repro.stream.shard import ShardContext
 
     ctx = ShardContext.create()
-
-    if mode == "probe":
-        # one collective round-trip: every shard contributes its id, all
-        # must see the full set back
-        got = ctx.merger.union({ctx.shard_id})
-        ok = got == set(range(ctx.n_shards))
-        print("DIGEST", "probe")
-        print("AGREE", int(ok), flush=True)
-        raise SystemExit(0 if ok else 1)
 
     if mode == "lattice":
         from repro.core.global_grounding import build_global_grounding

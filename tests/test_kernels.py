@@ -16,7 +16,8 @@ from repro.kernels.common import assert_allclose
 
 jax.config.update("jax_enable_x64", False)
 
-SHAPES_PP = [(8, 8), (16, 16), (128, 128), (96, 96), (130, 130), (33, 33)]
+# 600 exceeds one 512-wide pair block, so the multi-block accumulation runs
+SHAPES_PP = [(8, 8), (16, 16), (128, 128), (96, 96), (130, 130), (33, 33), (600, 600)]
 DTYPES = [jnp.float32, jnp.bfloat16]
 
 
@@ -80,7 +81,7 @@ def test_icm_sweep_batch(B, P):
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("B,S,P", [(1, 1, 8), (2, 4, 16), (3, 5, 96), (2, 2, 130)])
+@pytest.mark.parametrize("B,S,P", [(1, 1, 8), (2, 4, 16), (3, 5, 96), (2, 2, 130), (2, 130, 600)])
 @pytest.mark.parametrize("dtype", [jnp.float32])
 def test_mln_score_sets(B, S, P, dtype):
     from repro.kernels.mln_score import kernel, ref
@@ -194,3 +195,62 @@ def test_flash_attn_matches_chunked_xla():
     xla = layers.chunked_attention(q, k, v, scale, causal=True, q_block=64)
     pallas = kernel.flash_attention(q, k, v, scale, causal=True, interpret=True)
     assert_allclose(pallas.reshape(xla.shape), xla, rtol=2e-3, atol=2e-3)
+
+
+# ---------------------------------------------------------------------------
+# routing and compile-cache placement (no chip needed)
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("env", ["off", "interpret"])
+def test_pallas_mode_refuses_fallback_on_tpu(monkeypatch, env):
+    """On a TPU backend the kernels run compiled; asking for the
+    references or the interpreter there raises instead of quietly
+    running something else."""
+    from repro.kernels import common
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.delenv("REPRO_PALLAS", raising=False)
+    assert common.pallas_mode() == "compiled"
+    monkeypatch.setenv("REPRO_PALLAS", env)
+    with pytest.raises(RuntimeError, match="REPRO_PALLAS"):
+        common.pallas_mode()
+
+
+def test_pallas_mode_off_tpu(monkeypatch):
+    from repro.kernels import common
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
+    monkeypatch.delenv("REPRO_PALLAS", raising=False)
+    assert common.pallas_mode() == "off"
+    monkeypatch.setenv("REPRO_PALLAS", "interpret")
+    assert common.pallas_mode() == "interpret"
+    monkeypatch.setenv("REPRO_PALLAS", "compiled")
+    with pytest.raises(ValueError, match="REPRO_PALLAS"):
+        common.pallas_mode()
+
+
+@pytest.mark.parametrize("from_env", [True, False])
+def test_compile_cache_dir(monkeypatch, tmp_path, from_env):
+    """The compile cache follows JAX_COMPILATION_CACHE_DIR (which JAX
+    reads itself, so nothing is set) or else sits at the fixed
+    ``<checkout>/.jax_cache``."""
+    import pathlib
+
+    from repro.kernels import common
+
+    checkout = pathlib.Path(__file__).resolve().parents[1]
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        if from_env:
+            monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+            assert common.use_compile_cache() == str(tmp_path)
+            assert jax.config.jax_compilation_cache_dir == before
+        else:
+            monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+            want = str(checkout / ".jax_cache")
+            assert common.use_compile_cache() == want
+            assert common.use_compile_cache() == want  # same path every call
+            assert jax.config.jax_compilation_cache_dir == want
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

@@ -15,8 +15,7 @@ Three layers, cheapest first:
 3. **Multi-process mesh**: N worker processes join a ``jax.distributed``
    CPU mesh (gloo collectives); every replica's digest must equal the
    single-host baseline, and the replicas must agree among themselves
-   (``AGREE 1`` — a cross-process digest all-gather).  Gated by a probe
-   run because not every jax build ships a CPU collectives client.
+   (``AGREE 1`` — a cross-process digest all-gather).
 
 Digest equality is the ROADMAP item-1 correctness bar: bit-for-bit the
 single-host fixpoint, not approximately it.
@@ -278,31 +277,9 @@ def _run_mesh(mode, scheme, n_procs, *, perm_seed=-1, timeout=420):
     return outs
 
 
-_MESH_PROBE: dict[bool, str] = {}
-
-
-def _mesh_or_skip():
-    """Probe-and-skip: jax builds without a CPU collectives client (gloo)
-    cannot run cross-process CPU meshes — the CI matrix includes one."""
-    if not _MESH_PROBE:
-        try:
-            outs = _run_mesh("probe", "smp", 2, timeout=180)
-            ok = all(rc == 0 for rc, _, _ in outs)
-            detail = "" if ok else outs[0][2][-800:]
-        except Exception as e:  # pragma: no cover - spawn trouble
-            ok, detail = False, repr(e)
-        _MESH_PROBE[True] = "" if ok else detail
-    if _MESH_PROBE[True]:
-        pytest.skip(
-            "no multi-process CPU mesh on this jax build: "
-            + _MESH_PROBE[True]
-        )
-
-
 @pytest.mark.parametrize("scheme", ["smp", "mmp"])
 @pytest.mark.parametrize("n_procs", [2, 4])
 def test_mesh_hepth_digest_equals_single_host(hepth_baseline, n_procs, scheme):
-    _mesh_or_skip()
     outs = _run_mesh("hepth", scheme, n_procs)
     expect = hepth_baseline(scheme)
     for rc, out, err in outs:
@@ -314,7 +291,6 @@ def test_mesh_hepth_digest_equals_single_host(hepth_baseline, n_procs, scheme):
 
 @pytest.mark.parametrize("scheme", ["smp", "mmp"])
 def test_mesh_lattice_digest_equals_single_host(lattice_baseline, scheme):
-    _mesh_or_skip()
     outs = _run_mesh("lattice", scheme, 2)
     for rc, out, err in outs:
         assert rc == 0, f"shard failed rc={rc}\n{out}\n{err}"
@@ -323,7 +299,6 @@ def test_mesh_lattice_digest_equals_single_host(lattice_baseline, scheme):
 
 
 def test_mesh_permuted_schedule_digest(hepth_baseline):
-    _mesh_or_skip()
     outs = _run_mesh("hepth", "smp", 2, perm_seed=5)
     for rc, out, err in outs:
         assert rc == 0, f"shard failed rc={rc}\n{out}\n{err}"
