@@ -82,7 +82,7 @@ from repro.core.driver import (
     _promote,
     publish_em_result,
 )
-from repro.obs import profiler_session, record_transfer
+from repro.obs import record_transfer
 from repro.obs import span as obs_span
 from repro.core.global_grounding import GlobalGrounding
 from repro.core.mln import (
@@ -138,7 +138,7 @@ def register_ground_builder(kind: str, builder) -> None:
 
 
 def _mln_ground_builder(weights: MLNWeights):
-    def f(entity_ids, entity_mask, coauthor, sim_level, pair_mask):
+    def _ground_mln(entity_ids, entity_mask, coauthor, sim_level, pair_mask):
         batch = NeighborhoodBatch(
             entity_ids=entity_ids,
             entity_mask=entity_mask,
@@ -150,11 +150,11 @@ def _mln_ground_builder(weights: MLNWeights):
         g = ground(batch, weights)
         return g.u, g.u_raw, g.C, g.valid
 
-    return jax.jit(f)
+    return jax.jit(_ground_mln)
 
 
 def _rules_ground_builder(_cfg):
-    def f(entity_ids, entity_mask, coauthor, sim_level, pair_mask):
+    def _ground_rules(entity_ids, entity_mask, coauthor, sim_level, pair_mask):
         batch = NeighborhoodBatch(
             entity_ids=entity_ids,
             entity_mask=entity_mask,
@@ -166,7 +166,7 @@ def _rules_ground_builder(_cfg):
         lev, valid, n_shared, link = ground_structure(batch)
         return lev, n_shared, link, valid
 
-    return jax.jit(f)
+    return jax.jit(_ground_rules)
 
 
 def _embed_ground_builder(matcher):
@@ -670,7 +670,7 @@ def _promote_loop_fn(num_gids: int, num_coup: int, m_pad: int, k_pad: int):
     kept as the host baseline).
     """
 
-    def f(u, coup_p, coup_q, w_co, gidx, gseg, gvalid, base):
+    def _promote_loop(u, coup_p, coup_q, w_co, gidx, gseg, gvalid, base):
         # (K, Np) membership bitsets of the pool groups, scattered once;
         # padded members carry gseg == k_pad and land in a dropped row.
         add = (
@@ -700,7 +700,7 @@ def _promote_loop_fn(num_gids: int, num_coup: int, m_pad: int, k_pad: int):
         )
         return bits, promoted
 
-    return jax.jit(f)
+    return jax.jit(_promote_loop)
 
 
 class DevicePromoter:
@@ -793,21 +793,21 @@ class DevicePromoter:
         within the same sweep, so only the *match set* (identical by
         supermodularity) is bit-for-bit comparable across engines.
         """
-        groups = pool.groups()
-        if not groups:
-            return m_plus, 0
-        if not self.batched_ok:
-            self.host_scans += 1
-            with obs_span("rounds.promote", host=True):
+        with obs_span("rounds.promote") as sp:
+            groups = pool.groups()
+            if not groups:
+                return m_plus, 0
+            if not self.batched_ok:
+                self.host_scans += 1
+                sp.set(host=True)
                 return _promote(pool, self.gg, m_plus)
-        garrs = self._group_arrays(groups)
-        if garrs is None:
-            return m_plus, 0
-        gg = self.gg
-        gidx, gseg, gvalid, m_pad, k_pad = garrs
-        base0 = gg.bool_of(m_plus)
-        fn = _promote_loop_fn(len(gg.gids), len(gg.coup_p), m_pad, k_pad)
-        with obs_span("rounds.promote"):
+            garrs = self._group_arrays(groups)
+            if garrs is None:
+                return m_plus, 0
+            gg = self.gg
+            gidx, gseg, gvalid, m_pad, k_pad = garrs
+            base0 = gg.bool_of(m_plus)
+            fn = _promote_loop_fn(len(gg.gids), len(gg.coup_p), m_pad, k_pad)
             record_transfer("promoter", base0)
             bits, promoted = fn(
                 *self._device_grounding(), gidx, gseg, gvalid,
@@ -816,12 +816,12 @@ class DevicePromoter:
             # int() blocks on the dispatch, so the span bills the device
             # work it launched, not the next host sync
             promoted = int(promoted)
-        self.dispatches += 1
-        if promoted:
-            extra = gg.gids[np.asarray(bits) & ~base0]
-            if len(extra):
-                m_plus = m_plus.union(extra)
-        return m_plus, promoted
+            self.dispatches += 1
+            if promoted:
+                extra = gg.gids[np.asarray(bits) & ~base0]
+                if len(extra):
+                    m_plus = m_plus.union(extra)
+            return m_plus, promoted
 
 
 # ---------------------------------------------------------------------------
@@ -1013,6 +1013,19 @@ def _pad_rows(arrs: list[np.ndarray], mult: int) -> list[np.ndarray]:
 # ---------------------------------------------------------------------------
 
 
+def _pair_universe(packed: PackedCover) -> np.ndarray:
+    """Every candidate pair gid of the cover, sorted."""
+    return np.sort(np.asarray(sorted(packed.pair_levels.keys()), dtype=np.int64))
+
+
+def _no_pairs_result(init_matches: MatchStore | None, t0: float) -> EMResult:
+    """No candidate pairs anywhere: nothing to resolve."""
+    return EMResult(
+        init_matches if init_matches is not None else MatchStore(),
+        0, 0, 0, 0, time.perf_counter() - t0,
+    )
+
+
 def _seed_bits(universe: np.ndarray, m_plus: MatchStore) -> np.ndarray:
     Np = len(universe)
     bits = np.zeros(Np, dtype=bool)
@@ -1049,20 +1062,18 @@ def run_parallel(
     """Round-parallel NO-MP / SMP / MMP over the mesh's data axes.
 
     See :func:`_run_parallel_impl` for the engine semantics; this entry
-    point additionally (a) runs the whole call inside an opt-in
-    ``jax.profiler`` session (:func:`repro.obs.profiler_session`,
-    enabled via ``REPRO_JAX_PROFILE_DIR``) and (b) publishes the
-    :class:`EMResult` counters into the runtime metrics registry
-    (``em.*`` family).
+    point additionally opens the ``em.run`` span over the whole call and
+    publishes the :class:`EMResult` counters into the runtime metrics
+    registry (``em.*`` family).
     """
-    with profiler_session():
+    with obs_span("em.run", scheme=scheme):
         res = _run_parallel_impl(
             packed, matcher, gg, scheme=scheme, mesh=mesh,
             max_rounds=max_rounds, fast_rounds=fast_rounds, active=active,
             init_matches=init_matches, pool=pool, gcache=gcache,
             fused=fused,
         )
-    return publish_em_result(res)
+        return publish_em_result(res)
 
 
 def _run_parallel_impl(
@@ -1125,26 +1136,43 @@ def _run_parallel_impl(
     axes = tuple(mesh.axis_names)
     n_shards = int(np.prod(mesh.devices.shape))
 
-    universe = np.sort(np.asarray(sorted(packed.pair_levels.keys()), dtype=np.int64))
-    Np = len(universe)
-    if Np == 0:  # no candidate pairs anywhere: nothing to resolve
-        return EMResult(
-            init_matches if init_matches is not None else MatchStore(),
-            0, 0, 0, 0, time.perf_counter() - t0,
-        )
-
     if not fused:
         return _run_parallel_legacy(
             packed, matcher, gg, scheme=scheme, mesh=mesh,
             max_rounds=max_rounds, fast_rounds=fast_rounds, active=active,
-            init_matches=init_matches, pool=pool, t0=t0,
-            universe=universe, n_shards=n_shards,
+            init_matches=init_matches, pool=pool, t0=t0, n_shards=n_shards,
         )
 
-    bins = _prepare_bins(packed, universe, pad_mult=n_shards)
-    bin_ks = sorted(bins)
-    gcache = gcache if gcache is not None else GroundingCache()
-    mkey = _matcher_cache_key(matcher)
+    with obs_span("rounds.stage"):
+        universe = _pair_universe(packed)
+        Np = len(universe)
+        if Np == 0:  # no candidate pairs anywhere: nothing to resolve
+            return _no_pairs_result(init_matches, t0)
+        mkey = _matcher_cache_key(matcher)
+        base_kind = mkey[0]
+        if base_kind == "mln" and not getattr(matcher, "collective", True):
+            base_kind = "mln_greedy"
+        if scheme == "mmp" and base_kind not in ("mln", "mln_greedy"):
+            raise TypeError(
+                f"parallel MMP is wired to the MLN device promoter; kind "
+                f"{base_kind!r} emits no multi-pair messages, so run_mmp "
+                "(sequential) or scheme='smp' reach the identical fixpoint"
+            )
+        bins = _prepare_bins(packed, universe, pad_mult=n_shards)
+        bin_ks = sorted(bins)
+        dev_uidx = {
+            k: kcommon.put_sharded(bins[k].uidx, mesh, axes) for k in bin_ks
+        }
+        dev_pmask = {
+            k: kcommon.put_sharded(bins[k].pair_mask, mesh, axes)
+            for k in bin_ks
+        }
+        gcache = gcache if gcache is not None else GroundingCache()
+        # step-7 promotion runs on device (batched delta checks, zero
+        # host coupling-COO scans); the promoter counts any host fallback.
+        promoter = DevicePromoter(gg) if scheme == "mmp" else None
+        m_plus = init_matches if init_matches is not None else MatchStore()
+        m_bits = _seed_bits(universe, m_plus)
 
     _rk_memo: dict[int, tuple | None] = {}
 
@@ -1198,10 +1226,6 @@ def _run_parallel_impl(
             )
         return g
 
-    dev_uidx = {k: kcommon.put_sharded(bins[k].uidx, mesh, axes) for k in bin_ks}
-    dev_pmask = {
-        k: kcommon.put_sharded(bins[k].pair_mask, mesh, axes) for k in bin_ks
-    }
     evictions0 = gcache.evictions
     cold0 = gcache.cold_regrounds
     gcache.begin_peak_window()
@@ -1218,22 +1242,6 @@ def _run_parallel_impl(
         gcache.capacity is not None and gcache.capacity < len(bin_ks)
     )
 
-    base_kind = mkey[0]
-    if base_kind == "mln" and not getattr(matcher, "collective", True):
-        base_kind = "mln_greedy"
-    if scheme == "mmp" and base_kind not in ("mln", "mln_greedy"):
-        raise TypeError(
-            f"parallel MMP is wired to the MLN device promoter; kind "
-            f"{base_kind!r} emits no multi-pair messages, so run_mmp "
-            "(sequential) or scheme='smp' reach the identical fixpoint"
-        )
-
-    # step-7 promotion runs on device (batched delta checks, zero host
-    # coupling-COO scans); the promoter counts any host fallback.
-    promoter = DevicePromoter(gg) if scheme == "mmp" else None
-
-    m_plus = init_matches if init_matches is not None else MatchStore()
-    m_bits = _seed_bits(universe, m_plus)
     if pool is None:
         pool = MessagePool()
     active = (
@@ -1281,36 +1289,39 @@ def _run_parallel_impl(
     # picked (rounded up so the compiled shape is stable across calls)
     hist_cap = ((max_rounds + _HISTORY_CAP - 1) // _HISTORY_CAP) * _HISTORY_CAP
 
-    def fused_call(kind, act_masks, budget):
+    def fused_call(kind, act_list, budget):
         nonlocal dispatches
-        spec = FusedSpec(
-            kinds=tuple(kind for _ in bin_ks),
-            ks=tuple(bin_ks),
-            batch=tuple(bins[k].entity_mask.shape[0] for k in bin_ks),
-            num_pairs=tuple(bins[k].pair_mask.shape[1] for k in bin_ks),
-            universe_size=Np,
-            history_cap=hist_cap,
-        )
-        fn = build_fused_fn(spec, mesh, axes)
-        args = []
-        for k in bin_ks:
-            args += list(dispatch_grounds(k))
+        with obs_span("rounds.schedule"):
+            act_masks = masks_for(act_list)
+            spec = FusedSpec(
+                kinds=tuple(kind for _ in bin_ks),
+                ks=tuple(bin_ks),
+                batch=tuple(bins[k].entity_mask.shape[0] for k in bin_ks),
+                num_pairs=tuple(bins[k].pair_mask.shape[1] for k in bin_ks),
+                universe_size=Np,
+                history_cap=hist_cap,
+            )
+            fn = build_fused_fn(spec, mesh, axes)
+            args = []
+            for k in bin_ks:
+                args += list(dispatch_grounds(k))
+                args += [
+                    dev_uidx[k], dev_pmask[k],
+                    kcommon.put_sharded(act_masks[k], mesh, axes),
+                ]
             args += [
-                dev_uidx[k], dev_pmask[k],
-                kcommon.put_sharded(act_masks[k], mesh, axes),
-            ]
-        with obs_span("rounds.fused", kind=kind):
-            bits, r, ev, hist = fn(
-                *args,
                 kcommon.put_replicated(m_bits, mesh),
                 kcommon.put_replicated(np.asarray(budget, np.int32), mesh),
-            )
+            ]
+        with obs_span("rounds.fused", kind=kind):
+            bits, r, ev, hist = fn(*args)
             # int() blocks on the while_loop, so the span owns its time
             r = int(r)
-        dispatches += 1
-        # np.array (not asarray): callers assign this to m_bits and
-        # mutate it in place, and asarray of a jax buffer is read-only
-        return np.array(bits), r, int(ev), [int(h) for h in np.asarray(hist)[:r]]
+            dispatches += 1
+            # np.array (not asarray): callers assign this to m_bits and
+            # mutate it in place, and asarray of a jax buffer is read-only
+            return (np.array(bits), r, int(ev),
+                    [int(h) for h in np.asarray(hist)[:r]])
 
     def finish():
         return EMResult(
@@ -1335,13 +1346,14 @@ def _run_parallel_impl(
         """One host-visible full round: per-bin full-shape dispatches.
         Returns (newly matched gids, messages).  Mutates m_bits/m_plus."""
         nonlocal dispatches, evals, rounds, full_rounds, m_bits, m_plus
-        act_masks = masks_for(act_list)
-        history.append(len(act_list))
-        rounds += 1
-        full_rounds += 1
-        new_bits = m_bits.copy()
-        round_msgs: list[list[int]] = []
-        m_bits_dev = kcommon.put_replicated(m_bits, mesh)
+        with obs_span("rounds.schedule"):
+            act_masks = masks_for(act_list)
+            history.append(len(act_list))
+            rounds += 1
+            full_rounds += 1
+            new_bits = m_bits.copy()
+            round_msgs: list[list[int]] = []
+            m_bits_dev = kcommon.put_replicated(m_bits, mesh)
         with obs_span("rounds.full", active=len(act_list)):
             for k in bin_ks:
                 am = act_masks[k]
@@ -1363,13 +1375,30 @@ def _run_parallel_impl(
                 evals += int(am.sum())
                 new_bits |= np.asarray(bits)
                 if scheme == "mmp" and collective:
-                    round_msgs += _labels_to_messages(
-                        bins[k].pair_gid, np.asarray(lab), m_plus, row_mask=am
-                    )
-        newly = universe[new_bits & ~m_bits]
-        m_bits = new_bits
-        m_plus = m_plus.union(newly)
+                    lab = np.asarray(lab)
+                    with obs_span("rounds.messages"):
+                        round_msgs += _labels_to_messages(
+                            bins[k].pair_gid, lab, m_plus, row_mask=am
+                        )
+        with obs_span("rounds.schedule"):
+            newly = universe[new_bits & ~m_bits]
+            m_bits = new_bits
+            m_plus = m_plus.union(newly)
         return newly, round_msgs
+
+    def promote():
+        """Step 7 over the pool; folds what it promotes into m_plus and
+        m_bits and returns those gids (None when nothing was promoted)."""
+        nonlocal m_plus, promoted_total
+        m_plus2, promoted = promoter.promote(pool, m_plus)
+        promoted_total += promoted
+        if not promoted:
+            return None
+        with obs_span("rounds.schedule"):
+            extra = m_plus2.difference(m_plus)
+            m_plus = m_plus2
+            _set_bits(m_bits, universe, extra)
+        return extra
 
     if scheme == "nomp":
         # one round, no exchange: a single fused dispatch for cheap
@@ -1381,10 +1410,9 @@ def _run_parallel_impl(
             if collective or spill_mode:
                 full_round_over(active)
             else:
-                bits, rounds, evals, history = fused_call(
-                    base_kind, masks_for(active), 1
-                )
-                m_plus = m_plus.union(universe[bits & ~m_bits])
+                bits, rounds, evals, history = fused_call(base_kind, active, 1)
+                with obs_span("rounds.schedule"):
+                    m_plus = m_plus.union(universe[bits & ~m_bits])
         return finish()
 
     if scheme == "smp" and not collective and not spill_mode:
@@ -1394,9 +1422,10 @@ def _run_parallel_impl(
         # below, which stages one bin's tensors at a time.)
         if active:
             bits, rounds, evals, history = fused_call(
-                base_kind, masks_for(active), max_rounds
+                base_kind, active, max_rounds
             )
-            m_plus = m_plus.union(universe[bits & ~m_bits])
+            with obs_span("rounds.schedule"):
+                m_plus = m_plus.union(universe[bits & ~m_bits])
         return finish()
 
     # -- SMP and MMP: host-visible full rounds + fused greedy segments. ---
@@ -1426,37 +1455,33 @@ def _run_parallel_impl(
             cand.update(packed.neighborhoods_of_slot_pairs(changed))
         return sorted(cand)
 
-    active = live_rows(active)
+    with obs_span("rounds.schedule"):
+        active = live_rows(active)
     if scheme == "mmp" and seeds and not active:
         # every seed is inert, but the (streaming-persistent) pool must
         # still be replayed against the current grounding — exactly what
         # run_mmp's step 7 does after evaluating those seeds
-        m_plus2, promoted = promoter.promote(pool, m_plus)
-        promoted_total += promoted
-        if promoted:
-            extra = m_plus2.difference(m_plus)
-            m_plus = m_plus2
-            _set_bits(m_bits, universe, extra)
-            active = packed.neighborhoods_of_slot_pairs(extra)
+        extra = promote()
+        if extra is not None:
+            with obs_span("rounds.schedule"):
+                active = packed.neighborhoods_of_slot_pairs(extra)
     while active and rounds < max_rounds:
         if greedy_ok and not full_round:
             bits, r, ev, hist = fused_call(
-                "mln_greedy", masks_for(active), max_rounds - rounds
+                "mln_greedy", active, max_rounds - rounds
             )
-            rounds += r
-            evals += ev
-            history += hist
-            newly = universe[bits & ~m_bits]
-            m_bits = bits
-            m_plus = m_plus.union(newly)
+            with obs_span("rounds.schedule"):
+                rounds += r
+                evals += ev
+                history += hist
+                newly = universe[bits & ~m_bits]
+                m_bits = bits
+                m_plus = m_plus.union(newly)
             if scheme == "mmp":
-                m_plus2, promoted = promoter.promote(pool, m_plus)
-                promoted_total += promoted
-                if promoted:
-                    extra = m_plus2.difference(m_plus)
-                    m_plus = m_plus2
-                    _set_bits(m_bits, universe, extra)
-                    active = packed.neighborhoods_of_slot_pairs(extra)
+                extra = promote()
+                if extra is not None:
+                    with obs_span("rounds.schedule"):
+                        active = packed.neighborhoods_of_slot_pairs(extra)
                     if active:
                         continue
             # greedy closure quiescent: one full round over every
@@ -1464,24 +1489,23 @@ def _run_parallel_impl(
             # candidate slot (fresh maximal messages / collective
             # promotions) before declaring the fixpoint
             full_round = True
-            active = live_rows(certify_rows())
+            with obs_span("rounds.schedule"):
+                active = live_rows(certify_rows())
             continue
 
         newly, round_msgs = full_round_over(active)
         if scheme == "mmp":
-            for msg in round_msgs:
-                pool.add_message(msg)
-                emitted += 1
-            m_plus2, promoted = promoter.promote(pool, m_plus)
-            promoted_total += promoted
-            if promoted:
-                extra = m_plus2.difference(m_plus)
+            with obs_span("rounds.schedule"):
+                for msg in round_msgs:
+                    pool.add_message(msg)
+                    emitted += 1
+            extra = promote()
+            if extra is not None:
                 newly = np.unique(np.concatenate([newly, extra]))
-                m_plus = m_plus2
-                _set_bits(m_bits, universe, extra)
-        active = (
-            packed.neighborhoods_of_slot_pairs(newly) if len(newly) else []
-        )
+        with obs_span("rounds.schedule"):
+            active = (
+                packed.neighborhoods_of_slot_pairs(newly) if len(newly) else []
+            )
         if greedy_ok and active:
             full_round = False
     return finish()
@@ -1500,7 +1524,6 @@ def _run_parallel_legacy(
     init_matches: MatchStore | None,
     pool: MessagePool | None,
     t0: float,
-    universe: np.ndarray,
     n_shards: int,
 ) -> EMResult:
     """The pre-fusion host round loop: one dispatch per bin per round,
@@ -1508,7 +1531,10 @@ def _run_parallel_legacy(
     Kept as the differential baseline (tests assert bit-for-bit equality
     with the fused engine; ``table1_parallel`` reports the speedup)."""
     axes = tuple(mesh.axis_names)
+    universe = _pair_universe(packed)
     Np = len(universe)
+    if Np == 0:
+        return _no_pairs_result(init_matches, t0)
     bins = _prepare_bins(packed, universe)
 
     m_plus = init_matches if init_matches is not None else MatchStore()

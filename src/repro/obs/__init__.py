@@ -9,13 +9,15 @@ report dataclasses and benchmark plumbing:
 * :mod:`repro.obs.registry` — process-wide, thread-safe counters /
   gauges / histograms (exact p50/p90/p99); ``get_registry()`` /
   ``reset()``.
-* :mod:`repro.obs.tracing` — nestable ``span()`` context managers with
-  optional device fencing; the serving span taxonomy is in the module
-  docstring and ``docs/ARCHITECTURE.md``.
+* :mod:`repro.obs.tracing` — nestable ``span()`` context managers that
+  also annotate a running ``jax.profiler`` trace, one ``trace_id`` per
+  root span, and ``compile`` spans / ``compile.*`` counters from
+  ``jax.monitoring``; the span taxonomy is in the module docstring and
+  ``docs/ARCHITECTURE.md``.
 * :mod:`repro.obs.transfer` — host→device upload-byte accounting for
   the three transfer sites (grounding cache, promoter, bin staging).
-* :mod:`repro.obs.export` — JSON snapshots, Chrome-trace/Perfetto
-  ``trace_event`` files, opt-in ``jax.profiler`` sessions.
+* :mod:`repro.obs.export` — JSON snapshots and Chrome-trace/Perfetto
+  ``trace_event`` files on the profiler's (Unix-epoch) clock.
 * :mod:`repro.obs.quality` — the paper's quality metrics
   (:mod:`repro.core.metrics`), re-exported so runtime and quality
   numbers report through one surface.
@@ -38,11 +40,7 @@ counters ``serve.retries`` / ``serve.quarantined`` /
 exercises under injected faults.
 """
 
-from repro.obs.export import (  # noqa: F401
-    profiler_session,
-    write_chrome_trace,
-    write_snapshot,
-)
+from repro.obs.export import write_chrome_trace, write_snapshot  # noqa: F401
 from repro.obs.registry import (  # noqa: F401
     MetricsRegistry,
     get_registry,
@@ -56,7 +54,6 @@ __all__ = [
     "Span",
     "SpanRecord",
     "get_registry",
-    "profiler_session",
     "record_transfer",
     "reset",
     "span",

@@ -1,6 +1,6 @@
-"""Exporters: JSON snapshots, Chrome-trace files, jax.profiler sessions.
+"""Exporters: JSON snapshots and Chrome-trace files.
 
-Three ways out of the registry:
+Two ways out of the registry:
 
 * :func:`write_snapshot` — ``MetricsRegistry.snapshot()`` as a JSON
   file; what the benchmarks commit into ``BENCH_*.json`` blocks.
@@ -8,24 +8,20 @@ Three ways out of the registry:
   ``trace_event`` file (``{"traceEvents": [...]}``, complete ``"X"``
   events in microseconds).  Loads in ``chrome://tracing`` and
   `Perfetto <https://ui.perfetto.dev>`_; CI exports one per push from a
-  hepth ingest and uploads it as a workflow artifact.
-* :func:`profiler_session` — an opt-in ``jax.profiler`` trace around a
-  region (``run_parallel`` wraps itself in one).  Enabled by passing a
-  ``logdir`` or setting ``REPRO_JAX_PROFILE_DIR``; a no-op otherwise,
-  so the hot path never pays for it.
+  hepth ingest and uploads it as a workflow artifact.  Its timestamps
+  are Unix-epoch microseconds, the clock of a ``jax.profiler`` trace,
+  so the file lines up with the profiler's ``perfetto_trace.json.gz``
+  of the same stretch (the spans are also inside that trace, as
+  ``TraceAnnotation`` events: :mod:`repro.obs.tracing`).
 """
 
 from __future__ import annotations
 
-import contextlib
 import json
-import os
 
 from repro.obs.registry import MetricsRegistry, get_registry
 
-__all__ = ["profiler_session", "write_chrome_trace", "write_snapshot"]
-
-PROFILE_ENV = "REPRO_JAX_PROFILE_DIR"
+__all__ = ["write_chrome_trace", "write_snapshot"]
 
 
 def write_snapshot(path: str, registry: MetricsRegistry | None = None) -> dict:
@@ -41,12 +37,13 @@ def write_snapshot(path: str, registry: MetricsRegistry | None = None) -> dict:
 def chrome_trace_events(registry: MetricsRegistry | None = None) -> list[dict]:
     """The span log as Chrome ``trace_event`` dicts (phase ``X``).
 
-    Timestamps are microseconds relative to the registry's ``t0`` (its
-    creation or last reset), one ``tid`` per recording thread, so the
-    viewer reconstructs the nesting of concurrent ingests and readers.
+    Timestamps are microseconds since the Unix epoch (the registry's
+    clock pair, :meth:`~repro.obs.registry.MetricsRegistry.epoch_us`),
+    one ``tid`` per recording thread, so the viewer reconstructs the
+    nesting of concurrent ingests and readers; each event's args carry
+    its ``trace_id``.
     """
     reg = registry if registry is not None else get_registry()
-    t0 = reg.t0
     events: list[dict] = [{
         "name": "process_name",
         "ph": "M",
@@ -60,7 +57,7 @@ def chrome_trace_events(registry: MetricsRegistry | None = None) -> list[dict]:
         ev = {
             "name": rec.name,
             "ph": "X",
-            "ts": round((rec.t_start - t0) * 1e6, 3),
+            "ts": round(reg.epoch_us(rec.t_start), 3),
             "dur": round(rec.dur_s * 1e6, 3),
             "pid": 0,
             "tid": rec.thread_id % (1 << 31),
@@ -68,8 +65,8 @@ def chrome_trace_events(registry: MetricsRegistry | None = None) -> list[dict]:
         args = dict(rec.args) if rec.args else {}
         if rec.parent:
             args["parent"] = rec.parent
-        if args:
-            ev["args"] = args
+        args["trace_id"] = rec.trace_id
+        ev["args"] = args
         events.append(ev)
     return events
 
@@ -86,30 +83,3 @@ def write_chrome_trace(path: str,
         json.dump({"traceEvents": events, "displayTimeUnit": "ms"}, f)
         f.write("\n")
     return len(events) - 1
-
-
-@contextlib.contextmanager
-def profiler_session(logdir: str | None = None):
-    """Opt-in ``jax.profiler`` trace around a region.
-
-    Activates when ``logdir`` is given or ``REPRO_JAX_PROFILE_DIR`` is
-    set; yields True when a trace is running, False when it is a no-op.
-    Sessions do not nest: if one is already active (jax raises), the
-    inner region silently runs untraced — the outer session owns the
-    trace.
-    """
-    logdir = logdir or os.environ.get(PROFILE_ENV)
-    if not logdir:
-        yield False
-        return
-    import jax
-
-    try:
-        jax.profiler.start_trace(logdir)
-    except Exception:
-        yield False  # an outer session is already tracing
-        return
-    try:
-        yield True
-    finally:
-        jax.profiler.stop_trace()

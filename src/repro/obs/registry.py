@@ -168,9 +168,11 @@ class MetricsRegistry:
     ``spans`` is an append-only list of
     :class:`repro.obs.tracing.SpanRecord`, capped at ``max_spans``
     (oldest dropped, ``spans_dropped`` counts them) so a long-lived
-    service cannot grow the trace without bound.  ``t0`` anchors the
-    Chrome-trace timebase: span timestamps are ``perf_counter`` values,
-    exported relative to it.
+    service cannot grow the trace without bound.  ``clock`` is a
+    ``(time.time_ns(), perf_counter_ns())`` pair taken at creation and at
+    :meth:`reset`: span timestamps are ``perf_counter`` values, and the
+    pair puts them on the Unix-epoch clock the ``jax.profiler`` trace
+    uses (:meth:`epoch_us`).
     """
 
     def __init__(self, max_spans: int = 1 << 16):
@@ -182,7 +184,7 @@ class MetricsRegistry:
         self.max_spans = max_spans
         self.spans_dropped = 0
         self.tracing = True
-        self.t0 = time.perf_counter()
+        self.clock = (time.time_ns(), time.perf_counter_ns())
 
     # -- instrument accessors (create on first touch) ---------------------
 
@@ -225,6 +227,12 @@ class MetricsRegistry:
     def set_tracing(self, enabled: bool) -> None:
         self.tracing = bool(enabled)
 
+    def epoch_us(self, perf_s: float) -> float:
+        """A ``perf_counter`` time in microseconds since the Unix epoch,
+        the clock of the ``jax.profiler`` trace."""
+        wall_ns, perf_ns = self.clock
+        return (wall_ns - perf_ns) / 1e3 + perf_s * 1e6
+
     # -- lifecycle --------------------------------------------------------
 
     def reset(self) -> None:
@@ -243,7 +251,7 @@ class MetricsRegistry:
                 h.samples.clear()
             self.spans.clear()
             self.spans_dropped = 0
-            self.t0 = time.perf_counter()
+            self.clock = (time.time_ns(), time.perf_counter_ns())
 
     def snapshot(self) -> dict:
         """One consistent JSON-ready view of everything.

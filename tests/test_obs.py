@@ -11,7 +11,11 @@ Covers the ISSUE-6 acceptance surface:
 * device-transfer accounting plumbed through ``IngestReport``;
 * registry-backed counters staying consistent with the dataclass views;
 * tracing overhead on the ingest path bounded (<5% + noise slack);
-* Chrome-trace/JSON exporters producing parseable output.
+* Chrome-trace/JSON exporters producing parseable output, on the
+  profiler's Unix-epoch clock;
+* the round engine's span tree under one ``em.run`` root and its
+  ``trace_id``, spans inside a ``jax.profiler`` trace, and the
+  ``compile`` span / ``compile.*`` counters with tracing on and off.
 """
 
 from __future__ import annotations
@@ -132,7 +136,6 @@ def test_span_disabled_is_noop():
     reg.set_tracing(False)
     with obs.span("quiet", arg=1) as s:
         s.set(more=2)
-        assert s.fence(123) == 123
     assert reg.spans == []
 
 
@@ -318,6 +321,11 @@ def test_chrome_trace_export(tmp_path, hepth_small):
     assert len(roots) == 2
     kids = [e for e in xs if e.get("args", {}).get("parent") == "ingest"]
     assert kids
+    # every event of one ingest carries that ingest's trace id
+    assert {e["args"]["trace_id"] for e in kids} == {
+        e["args"]["trace_id"] for e in roots}
+    # timestamps are Unix-epoch microseconds, the profiler's clock
+    assert abs(min(e["ts"] for e in xs) / 1e6 - time.time()) < 600
 
 
 def test_snapshot_export(tmp_path):
@@ -332,10 +340,157 @@ def test_snapshot_export(tmp_path):
     assert on_disk["histograms"]["c"]["count"] == 1
 
 
-def test_profiler_session_noop_without_logdir(monkeypatch):
-    monkeypatch.delenv("REPRO_JAX_PROFILE_DIR", raising=False)
-    with obs.profiler_session() as active:
-        assert active is False
+# ---------------------------------------------------------------------------
+# Round engine spans, trace ids, the profiler's clock, compile spans
+# ---------------------------------------------------------------------------
+
+
+def test_run_parallel_spans_share_one_em_run_root(hepth_small):
+    from repro.core import pipeline
+    from repro.core.mln import MLNMatcher
+    from repro.core.parallel import run_parallel
+
+    packed, gg, _ = pipeline.prepare(hepth_small.entities,
+                                     hepth_small.relations)
+    reg = obs.get_registry()
+    ids = []
+    for _ in range(2):
+        obs.reset()
+        run_parallel(packed, MLNMatcher(), gg, scheme="mmp")
+        spans = list(reg.spans)
+        roots = [s for s in spans if s.depth == 0]
+        assert [s.name for s in roots] == ["em.run"]
+        root = roots[0]
+        ids.append(root.trace_id)
+        assert {s.trace_id for s in spans} == {root.trace_id}
+        names = {s.name for s in spans}
+        assert {"rounds.stage", "rounds.schedule", "rounds.full",
+                "rounds.messages", "rounds.promote"} <= names, names
+        for s in spans:
+            assert root.t_start <= s.t_start
+            assert s.t_start + s.dur_s <= root.t_start + root.dur_s + 1e-6
+            if s.name in ("rounds.stage", "rounds.schedule", "rounds.full",
+                          "rounds.fused", "rounds.promote"):
+                assert s.parent == "em.run", (s.name, s.parent)
+        # rounds.messages is the one new span nested in a full round
+        assert {s.name for s in spans if s.parent == "rounds.full"} <= {
+            "rounds.messages", "rounds.ground", "compile"}
+        assert all(s.parent == "rounds.full" for s in spans
+                   if s.name == "rounds.messages")
+    assert ids[0] != ids[1]
+
+
+def test_trace_id_is_inherited_and_fresh_per_root():
+    reg = obs.get_registry()
+    with obs.span("a"):
+        with obs.span("a.child"):
+            pass
+    with obs.span("b"):
+        pass
+    seen = {}
+
+    def other_thread():
+        with obs.span("c"):
+            with obs.span("c.child"):
+                pass
+
+    t = threading.Thread(target=other_thread)
+    t.start()
+    t.join()
+    for s in reg.spans:
+        seen[s.name] = s.trace_id
+    assert seen["a"] == seen["a.child"]
+    assert seen["c"] == seen["c.child"]
+    assert len({seen["a"], seen["b"], seen["c"]}) == 3
+
+
+def test_spans_land_in_the_profiler_trace_on_its_clock(tmp_path):
+    import glob
+    import os
+
+    import jax
+    import jax.numpy as jnp
+    from jax.profiler import ProfileData
+
+    from repro.obs.export import chrome_trace_events
+
+    reg = obs.get_registry()
+    x = jnp.ones(8)
+    (x * 2).block_until_ready()
+    with jax.profiler.trace(str(tmp_path)):
+        obs.reset()  # a fresh clock pair, a moment before the spans
+        with obs.span("probe.outer"):
+            with obs.span("probe.inner"):
+                (x * 2).block_until_ready()
+                time.sleep(0.002)
+    path = max(glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True),
+               key=os.path.getmtime)
+    pd = ProfileData.from_file(path)
+    t0_ns = None
+    found = {}
+    for plane in pd.planes:
+        if plane.name == "Task Environment":
+            t0_ns = int(dict(plane.stats)["profile_start_time"])
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith("probe."):
+                    found[e.name] = (e.start_ns, e.duration_ns)
+    recs = {s.name: s for s in reg.spans}
+    exported = {e["name"]: e for e in chrome_trace_events() if e["ph"] == "X"}
+    for name in ("probe.outer", "probe.inner"):
+        start_ns, dur_ns = found[name]
+        trace_us = (t0_ns + start_ns) / 1e3
+        assert abs(trace_us - reg.epoch_us(recs[name].t_start)) < 100, name
+        assert abs(dur_ns / 1e3 - recs[name].dur_s * 1e6) < 100, name
+        # the exported Chrome trace is on the same clock
+        assert abs(exported[name]["ts"] - trace_us) < 100, name
+
+
+def test_compile_inside_a_span_is_a_nested_compile_span():
+    import jax
+    import jax.numpy as jnp
+
+    reg = obs.get_registry()
+    x = jnp.arange(5.0)
+    n0 = reg.value("compile.programs")
+
+    def freshly_jitted(v):
+        return jnp.sin(v) * 3.0 + 1.0
+
+    with obs.span("outer"):
+        jax.jit(freshly_jitted)(x).block_until_ready()
+    assert reg.value("compile.programs") > n0
+    outer = next(s for s in reg.spans if s.name == "outer")
+    comp = [s for s in reg.spans if s.name == "compile"
+            and "freshly_jitted" in (s.args or {}).get("program", "")]
+    assert comp
+    for c in comp:
+        assert c.parent == "outer" and c.depth == 1
+        assert c.trace_id == outer.trace_id
+        assert outer.t_start <= c.t_start + 1e-3
+        assert c.t_start + c.dur_s <= outer.t_start + outer.dur_s + 1e-6
+
+
+def test_tracing_off_records_no_span_but_counts_compiles():
+    import jax
+    import jax.numpy as jnp
+
+    reg = obs.get_registry()
+    x = jnp.arange(5.0)
+    reg.set_tracing(False)
+    n0 = reg.value("compile.programs")
+    m0 = reg.value("compile.cache_misses")
+
+    def freshly_jitted_untraced(v):
+        return jnp.cos(v) - 2.0
+
+    with obs.span("quiet"):
+        jax.jit(freshly_jitted_untraced)(x).block_until_ready()
+    # a persistent-cache write, as JAX reports it
+    jax.monitoring.record_event("/jax/compilation_cache/cache_misses")
+    assert reg.spans == []
+    assert reg.value("compile.programs") > n0
+    assert reg.value("compile.cache_misses") == m0 + 1
 
 
 def test_quality_reexport_is_core_metrics():
