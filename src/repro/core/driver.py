@@ -52,6 +52,12 @@ class EMResult:
     # legacy fused=False loop and the sequential run_mmp count every
     # pass here by design (they ARE the host baseline).
     promote_host_scans: int = 0
+    # Pair slots of the round engine's staged bins (parallel engine):
+    # the candidate pairs over every neighborhood, and the slots staged
+    # for them (rows x width over every staged bin, padding included).
+    # Their ratio is the share of staged matcher work that is real.
+    candidate_slots: int = 0
+    staged_slots: int = 0
 
 
 # EMResult fields published as monotone ``em.*`` counters; the remaining
@@ -67,6 +73,8 @@ _EM_COUNTER_FIELDS = (
     "cache_evictions",
     "cold_regrounds",
     "promote_host_scans",
+    "candidate_slots",
+    "staged_slots",
 )
 
 

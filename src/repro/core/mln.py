@@ -103,8 +103,14 @@ class Grounding:
         return cls(*children)
 
 
-def ground_structure(batch: NeighborhoodBatch):
+def ground_structure(batch: NeighborhoodBatch, slot_i=None, slot_j=None):
     """Weight-independent grounded structure of a neighborhood batch.
+
+    ``slot_i``/``slot_j`` (B, P) int give the entity slots of each pair
+    slot of each row; by default every row holds the upper triangle
+    (``pairlib.triu_indices(k)``).  The round engine passes compacted
+    per-row slots (candidate pairs first, inert padding after), so
+    ``P`` is the staged width, not k(k-1)/2.
 
     Returns (lev, valid, n_shared, link):
       lev      (B, P) int32   similarity level (0 = not a candidate)
@@ -115,9 +121,15 @@ def ground_structure(batch: NeighborhoodBatch):
     Shared by the MLN (weights applied on top) and RULES matchers.
     """
     k = batch.k
-    ii, jj = pairlib.triu_indices(k)
-    ii = jnp.asarray(ii)
-    jj = jnp.asarray(jj)
+    B = batch.entity_mask.shape[0]
+    if slot_i is None:
+        ii, jj = pairlib.triu_indices(k)
+        slot_i = np.broadcast_to(ii, (B, len(ii)))
+        slot_j = np.broadcast_to(jj, (B, len(jj)))
+    # one-hot entity selectors: sel_i[b, p, a] = 1 iff a == slot_i[b, p]
+    slots = jnp.arange(k, dtype=jnp.int32)
+    sel_i = (jnp.asarray(slot_i)[:, :, None] == slots).astype(jnp.float32)
+    sel_j = (jnp.asarray(slot_j)[:, :, None] == slots).astype(jnp.float32)
 
     co = jnp.asarray(batch.coauthor, dtype=jnp.float32)  # (B, k, k)
     # Defensive: no self-coauthorship, no padded-slot edges.
@@ -130,30 +142,34 @@ def ground_structure(batch: NeighborhoodBatch):
 
     # Reflexive boost: n_shared[b, p] = |{d : co(i,d) & co(j,d)}|.
     shared = jnp.einsum("bid,bjd->bij", co, co)  # (B, k, k) counts
-    n_shared = shared[:, ii, jj]  # (B, P)
+    n_shared = jnp.sum(
+        jnp.einsum("bpa,bac->bpc", sel_i, shared) * sel_j, axis=2
+    )  # (B, P): shared[i_p, j_p]
     n_shared = jnp.where(valid, n_shared, 0.0)
 
     # Couplings: link(p, q) = (co[ip,iq] & co[jp,jq]) | (co[ip,jq] & co[jp,iq])
-    co_i = co[:, ii, :]  # (B, P, k)  coauthor rows of first endpoints
-    co_j = co[:, jj, :]  # (B, P, k)  coauthor rows of second endpoints
-    co_ii = co_i[:, :, ii]  # (B, P, P): co[i_p, i_q]
-    co_jj = co_j[:, :, jj]  # co[j_p, j_q]
-    co_ij = co_i[:, :, jj]  # co[i_p, j_q]
-    co_ji = co_j[:, :, ii]  # co[j_p, i_q]
+    # Every selection is a 0/1 product with one nonzero term: exact.
+    co_i = jnp.einsum("bpa,bac->bpc", sel_i, co)  # (B, P, k) co[i_p, :]
+    co_j = jnp.einsum("bpa,bac->bpc", sel_j, co)  # (B, P, k) co[j_p, :]
+    co_ii = jnp.einsum("bpc,bqc->bpq", co_i, sel_i)  # (B, P, P): co[i_p, i_q]
+    co_jj = jnp.einsum("bpc,bqc->bpq", co_j, sel_j)  # co[j_p, j_q]
+    co_ij = jnp.einsum("bpc,bqc->bpq", co_i, sel_j)  # co[i_p, j_q]
+    co_ji = jnp.einsum("bpc,bqc->bpq", co_j, sel_i)  # co[j_p, i_q]
     link = jnp.clip(co_ii * co_jj + co_ij * co_ji, 0.0, 1.0)
     vf = valid.astype(jnp.float32)
     pmask2 = vf[:, :, None] * vf[:, None, :]
-    P = len(pairlib.triu_indices(k)[0])
+    P = lev.shape[1]
     link = link * pmask2 * (1.0 - jnp.eye(P, dtype=jnp.float32))
     return lev, valid, n_shared, link
 
 
 def ground(
-    batch: NeighborhoodBatch, weights: MLNWeights
+    batch: NeighborhoodBatch, weights: MLNWeights, slot_i=None, slot_j=None
 ) -> Grounding:
-    """Ground the MLN rules on a padded neighborhood batch (jnp)."""
+    """Ground the MLN rules on a padded neighborhood batch (jnp); the
+    pair layout is :func:`ground_structure`'s."""
     w_sim, w_co = weights.as_arrays()
-    lev, valid, n_shared, link = ground_structure(batch)
+    lev, valid, n_shared, link = ground_structure(batch, slot_i, slot_j)
 
     u_raw = jnp.take(w_sim, lev) + w_co * n_shared
     u_raw = jnp.where(valid, u_raw, 0.0)
@@ -270,13 +286,16 @@ def _components(adj, nodes):
     return lab
 
 
-def _peel_and_promote(u, C, x, lab, valid, ev_neg):
+def _peel_and_promote(u, C, x, lab, valid, ev_neg, num_pairs=None):
     """Greedy-peel each component, activate those with joint delta >= 0.
 
     Group matrix G[l, p] = 1 iff lab[p] == l (l ranges over pair slots;
     component labels are min member indices so G rows are mostly empty).
     Peeling: drop members with negative marginal (u + C@(x + s))_p until
     none; then activate components whose joint delta >= -TIE_EPS.
+    ``num_pairs`` is the neighborhood's k(k-1)/2, which bounds the peel;
+    it defaults to the slot width, and a compacted row (fewer slots than
+    k(k-1)/2) must pass it so the bound does not shrink with the width.
     """
     P = u.shape[0]
     labels = jnp.arange(P, dtype=jnp.int32)
@@ -305,7 +324,7 @@ def _peel_and_promote(u, C, x, lab, valid, ev_neg):
     # result) — on an already-converged group matrix the peel costs ONE
     # (P, P) matmul instead of ~sqrt(2P) of them, which is what makes
     # quiescence-check rounds cheap.
-    peel_iters = int(np.ceil(np.sqrt(2 * P))) + 2
+    peel_iters = int(np.ceil(np.sqrt(2 * (num_pairs or P)))) + 2
 
     def peel_cond(state):
         _, i, changed = state
@@ -325,12 +344,14 @@ def _peel_and_promote(u, C, x, lab, valid, ev_neg):
     return x | newx
 
 
-def _infer_one(u, u_raw, C, ev_pos, ev_neg, valid):
+def _infer_one(u, u_raw, C, ev_pos, ev_neg, valid, num_pairs=None):
     """Full MAP inference for one neighborhood. Returns (x, lab).
 
     x   : (P,) bool final match set (includes evidence).
     lab : (P,) int32 entailment-component labels of *undecided* pairs
           (the maximal messages), P where not applicable.
+    num_pairs: the neighborhood's k(k-1)/2 when its P slots are a
+          compacted layout (see :func:`_peel_and_promote`).
     """
 
     def round_body(state):
@@ -340,7 +361,7 @@ def _infer_one(u, u_raw, C, ev_pos, ev_neg, valid):
         mutual = X & X.T
         undecided = valid & ~x1 & ~ev_neg
         lab = _components(mutual, undecided)
-        x2 = _peel_and_promote(u, C, x1, lab, valid, ev_neg)
+        x2 = _peel_and_promote(u, C, x1, lab, valid, ev_neg, num_pairs)
         x3 = _closure(u, C, x2 | ev_pos, ev_neg, valid)
         return x3, lab, jnp.any(x3 != x)
 

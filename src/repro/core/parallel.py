@@ -21,6 +21,13 @@ boundary sits exactly at the *quiescence points*:
   (``capacity`` / ``hbm_budget_bytes``) drops cold bins' tensors and
   re-grounds them on demand, bit-for-bit (see the class docstring).
 
+* **Staged layout** (:func:`_prepare_bins`): each neighborhood is
+  staged on its candidate pair slots, not on all k(k-1)/2 slots of its
+  size bin; a bin wider than ``_SLOT_STEP`` slots is split into sub-bins
+  by the width its rows need, so no program grounds, sweeps or reads
+  back the non-candidate slots (``EMResult.candidate_slots`` /
+  ``staged_slots`` count both).
+
 * **Fused multi-round closure** (:func:`build_fused_fn`): rounds that
   touch no host state — all NO-MP/SMP rounds, and MMP's ``fast_rounds``
   greedy re-activation rounds — run inside a single jitted
@@ -127,9 +134,11 @@ def _matcher_cache_key(matcher) -> tuple[str, object]:
 
 
 # kind -> builder(cfg) -> fn(entity_ids, entity_mask, coauthor,
-# sim_level, pair_mask) -> 4-tuple of (B, ...) device arrays with
-# ``valid`` last.  Plug-in families register here (and an eval fn in
-# _EVAL_KINDS) to run on the fused device engine.
+# sim_level, pair_mask, slot_i, slot_j) -> 4-tuple of (B, ...) device
+# arrays with ``valid`` last, on the staged pair layout (``slot_i`` /
+# ``slot_j`` give each pair slot's entity slots, see _prepare_bins).
+# Plug-in families register here (and an eval branch in _eval_bin_x) to
+# run on the fused device engine.
 _GROUND_BUILDERS: dict[str, object] = {}
 
 
@@ -138,7 +147,8 @@ def register_ground_builder(kind: str, builder) -> None:
 
 
 def _mln_ground_builder(weights: MLNWeights):
-    def _ground_mln(entity_ids, entity_mask, coauthor, sim_level, pair_mask):
+    def _ground_mln(entity_ids, entity_mask, coauthor, sim_level, pair_mask,
+                    slot_i, slot_j):
         batch = NeighborhoodBatch(
             entity_ids=entity_ids,
             entity_mask=entity_mask,
@@ -147,14 +157,15 @@ def _mln_ground_builder(weights: MLNWeights):
             pair_gid=pair_mask,
             pair_mask=pair_mask,
         )
-        g = ground(batch, weights)
+        g = ground(batch, weights, slot_i, slot_j)
         return g.u, g.u_raw, g.C, g.valid
 
     return jax.jit(_ground_mln)
 
 
 def _rules_ground_builder(_cfg):
-    def _ground_rules(entity_ids, entity_mask, coauthor, sim_level, pair_mask):
+    def _ground_rules(entity_ids, entity_mask, coauthor, sim_level, pair_mask,
+                      slot_i, slot_j):
         batch = NeighborhoodBatch(
             entity_ids=entity_ids,
             entity_mask=entity_mask,
@@ -163,7 +174,7 @@ def _rules_ground_builder(_cfg):
             pair_gid=pair_mask,
             pair_mask=pair_mask,
         )
-        lev, valid, n_shared, link = ground_structure(batch)
+        lev, valid, n_shared, link = ground_structure(batch, slot_i, slot_j)
         return lev, n_shared, link, valid
 
     return jax.jit(_ground_rules)
@@ -174,16 +185,26 @@ def _embed_ground_builder(matcher):
     matcher's append-only per-id embedding memo.  Pure in the entity
     ids (embeddings are deterministic per id and never mutated), so the
     grounding-cache splice/LRU contract holds exactly as for the jitted
-    kinds; only dirty rows' ids are ever (re-)encoded."""
+    kinds; only dirty rows' ids are ever (re-)encoded.  It grounds on
+    the upper triangle and gathers the staged slots from it."""
 
-    def f(entity_ids, entity_mask, coauthor, sim_level, pair_mask):
-        base, valid = matcher.ground_rows(
-            np.asarray(entity_ids), np.asarray(pair_mask)
+    def f(entity_ids, entity_mask, coauthor, sim_level, pair_mask,
+          slot_i, slot_j):
+        ids = np.asarray(entity_ids)
+        pm = np.asarray(pair_mask, dtype=bool)
+        B, k = ids.shape
+        # triangle slot of each staged slot; inert padding reads slot 0
+        tri = np.maximum(
+            pairlib.pair_slot_table(k)[np.asarray(slot_i), np.asarray(slot_j)], 0
         )
-        B = base.shape[0]
+        full_pm = np.zeros((B, pairlib.num_pairs(k)), dtype=bool)
+        rows, cols = np.nonzero(pm)
+        full_pm[rows, tri[rows, cols]] = True
+        base, _ = matcher.ground_rows(ids, full_pm)
+        base = np.take_along_axis(base, tri, axis=1) & pm
         return (
             jnp.asarray(base),
-            jnp.asarray(valid),
+            jnp.asarray(pm),
             jnp.zeros((B, 1, 1), jnp.float32),
             jnp.zeros((B, 1), jnp.float32),
         )
@@ -380,7 +401,9 @@ class GroundingCache:
                 + bt.entity_mask[r].tobytes()
                 + bt.coauthor[r].tobytes()
                 + bt.sim_level[r].tobytes()
-                + bt.pair_mask[r].tobytes(),
+                + bt.pair_mask[r].tobytes()
+                + bt.slot_i[r].tobytes()
+                + bt.slot_j[r].tobytes(),
                 digest_size=16,
             ).digest()
             for r in range(bt.entity_mask.shape[0])
@@ -395,17 +418,19 @@ class GroundingCache:
         co = bt.coauthor[rows]
         lv = bt.sim_level[rows]
         pm = bt.pair_mask[rows]
+        si = bt.slot_i[rows]
+        sj = bt.slot_j[rows]
         if pad:
             ids = np.concatenate(
                 [ids, np.full((pad,) + ids.shape[1:], -1, ids.dtype)]
             )
-            em = np.concatenate([em, np.zeros((pad,) + em.shape[1:], em.dtype)])
-            co = np.concatenate([co, np.zeros((pad,) + co.shape[1:], co.dtype)])
-            lv = np.concatenate([lv, np.zeros((pad,) + lv.shape[1:], lv.dtype)])
-            pm = np.concatenate([pm, np.zeros((pad,) + pm.shape[1:], pm.dtype)])
+            em, co, lv, pm, si, sj = (
+                np.concatenate([a, np.zeros((pad,) + a.shape[1:], a.dtype)])
+                for a in (em, co, lv, pm, si, sj)
+            )
         with obs_span("rounds.ground", rows=n):
-            record_transfer("gcache", ids, em, co, lv, pm)
-            out = fn(ids, em, co, lv, pm)
+            record_transfer("gcache", ids, em, co, lv, pm, si, sj)
+            out = fn(ids, em, co, lv, pm, si, sj)
         self.ground_calls += 1
         self.rows_ground += n
         return tuple(a[:n] for a in out) if pad else out
@@ -442,9 +467,9 @@ class GroundingCache:
             self.bin_hits += 1
         return arrays
 
-    def get(self, matcher_key, k: int, bt: _BinTensors,
+    def get(self, matcher_key, bin_key: tuple, bt: _BinTensors,
             row_keys: tuple | None = None) -> tuple:
-        key = (matcher_key, k)
+        key = (matcher_key, bin_key)
         sigs = self._row_sigs(bt, row_keys)
         cached = self._bins.get(key)
         if cached is not None and cached[0] == sigs and cached[1] is not None:
@@ -470,58 +495,147 @@ class GroundingCache:
 # ---------------------------------------------------------------------------
 
 
+# Pair-slot width step of a staged bin.  A bin whose k(k-1)/2 slots
+# exceed it is split into sub-bins of the smallest multiple of it that
+# holds each neighborhood's candidate pairs (capped at k(k-1)/2).
+_SLOT_STEP = 128
+
+
 @dataclasses.dataclass
 class _BinTensors:
-    """Per-bin device-ready tensors (host copies)."""
+    """Per-bin device-ready tensors (host copies) on the staged pair
+    layout: ``Pc`` slots per row, its candidate pairs first in their
+    upper-triangle order, then inert padding (``pair_mask`` False,
+    ``uidx`` == Np, ``pair_gid`` == -1)."""
 
     entity_ids: np.ndarray  # (B, k) int, -1 padding
     entity_mask: np.ndarray
     coauthor: np.ndarray
-    sim_level: np.ndarray
-    pair_mask: np.ndarray
-    uidx: np.ndarray  # (B, P) int32 universe index, Np where invalid
-    pair_gid: np.ndarray
+    sim_level: np.ndarray  # (B, Pc)
+    pair_mask: np.ndarray  # (B, Pc)
+    uidx: np.ndarray  # (B, Pc) int32 universe index, Np where invalid
+    pair_gid: np.ndarray  # (B, Pc)
+    slot_i: np.ndarray  # (B, Pc) int16 entity slot of each pair's first end
+    slot_j: np.ndarray  # (B, Pc) int16 ... and of its second end
+    rows: np.ndarray  # (B,) neighborhood of each row, -1 for padding
+
+
+@dataclasses.dataclass
+class _Staging:
+    """The staged bins of one cover and where each neighborhood sits."""
+
+    bins: dict[tuple[int, int], _BinTensors]  # (k, Pc) -> bin, sorted
+    bin_of: np.ndarray  # (N,) index into ``bins`` of each neighborhood
+    row_of: np.ndarray  # (N,) row within that bin
+    candidate_slots: int  # candidate pairs over every row
+    staged_slots: int  # B x Pc over every staged bin, padding included
+
+
+def _universe_index(universe: np.ndarray, pair_gid: np.ndarray) -> np.ndarray:
+    """Universe index of each pair gid; ``len(universe)`` where absent."""
+    Np = len(universe)
+    idx = np.clip(np.searchsorted(universe, pair_gid), 0, max(Np - 1, 0))
+    ok = (pair_gid >= 0) & (
+        universe[idx] == pair_gid if Np else np.zeros(pair_gid.shape, bool)
+    )
+    return np.where(ok, idx, Np).astype(np.int32)
 
 
 def _prepare_bins(
     packed: PackedCover, universe: np.ndarray, pad_mult: int = 1
-) -> dict[int, _BinTensors]:
-    """Stage per-bin tensors; ``pad_mult`` pads the batch axis up front
-    (padding rows are inert: ``pair_mask`` False, ``uidx`` == Np,
-    ``pair_gid`` == -1) so every later dispatch is full-bin shaped."""
-    out = {}
+) -> _Staging:
+    """Stage every neighborhood on its candidate pair slots.
+
+    A bin of k entities keeps its upper-triangle layout when k(k-1)/2 is
+    at most ``_SLOT_STEP``.  A wider bin is split into sub-bins keyed
+    ``(k, Pc)``: each row goes to the smallest multiple of the step that
+    holds its candidate pairs (capped at k(k-1)/2) and holds its
+    candidate slots in their upper-triangle order, inert padding after.
+    The order keeps every tie-break of the matcher (``argmin`` of the
+    peel, min-labels of the components) the same as on the full layout.
+    ``pad_mult`` pads each sub-bin's batch axis up front (padding rows
+    are inert too) so every later dispatch is full-bin shaped.
+    """
+    out: dict[tuple[int, int], _BinTensors] = {}
     Np = len(universe)
-    for k, nb in packed.bins.items():
-        idx = np.searchsorted(universe, nb.pair_gid)
-        idx = np.clip(idx, 0, max(Np - 1, 0))
-        ok = (nb.pair_gid >= 0) & (
-            universe[idx] == nb.pair_gid if Np else np.zeros_like(nb.pair_mask)
-        )
-        uidx = np.where(ok, idx, Np).astype(np.int32)
-        b = nb.entity_mask.shape[0]
-        target = max(((b + pad_mult - 1) // pad_mult) * pad_mult, pad_mult)
+    candidate = 0
 
-        def _pad(a, fill):
-            if target == b:
-                return a
-            extra = np.full((target - b,) + a.shape[1:], fill, dtype=a.dtype)
-            return np.concatenate([a, extra], axis=0)
+    def pad_rows(a, fill, target):
+        if target == a.shape[0]:
+            return a
+        extra = np.full((target - a.shape[0],) + a.shape[1:], fill, dtype=a.dtype)
+        return np.concatenate([a, extra], axis=0)
 
-        bt = _BinTensors(
-            entity_ids=_pad(nb.entity_ids, -1),
-            entity_mask=_pad(nb.entity_mask, False),
-            coauthor=_pad(nb.coauthor, False),
-            sim_level=_pad(nb.sim_level.astype(np.int8), 0),
-            pair_mask=_pad(nb.pair_mask, False),
-            uidx=_pad(uidx, Np),
-            pair_gid=_pad(nb.pair_gid, -1),
-        )
-        record_transfer(
-            "prepare", bt.entity_mask, bt.coauthor, bt.sim_level,
-            bt.pair_mask, bt.uidx, bt.pair_gid,
-        )
-        out[k] = bt
-    return out
+    for k, nb in sorted(packed.bins.items()):
+        ii, jj = pairlib.triu_indices(k)
+        B, P = nb.pair_mask.shape
+        # candidate slots in row-major order: per row, ascending slot
+        cr, cc = np.nonzero(nb.pair_mask)
+        candidate += len(cr)
+        if P <= _SLOT_STEP:
+            layouts = [(P, np.arange(B), None)]
+        else:
+            n_cand = np.bincount(cr, minlength=B)
+            steps = np.maximum(-(-n_cand // _SLOT_STEP), 1)
+            widths = np.minimum(steps * _SLOT_STEP, P)
+            pos = np.arange(len(cr)) - (np.cumsum(n_cand) - n_cand)[cr]
+            layouts = [(int(w), np.nonzero(widths == w)[0], pos)
+                       for w in np.unique(widths)]
+        for w, sel, pos in layouts:
+            target = max(-(-len(sel) // pad_mult) * pad_mult, pad_mult)
+            if pos is None:  # the upper-triangle layout, as packed
+                lev = nb.sim_level.astype(np.int8)
+                pm = nb.pair_mask
+                gid = nb.pair_gid
+                uidx = _universe_index(universe, gid)
+                si = np.broadcast_to(ii.astype(np.int16), (B, P))
+                sj = np.broadcast_to(jj.astype(np.int16), (B, P))
+            else:
+                sub = np.full(B, -1)
+                sub[sel] = np.arange(len(sel))
+                on = widths[cr] == w
+                r, c, p = sub[cr[on]], cc[on], pos[on]
+                src = (cr[on], c)
+
+                def place(vals, fill, dtype):
+                    a = np.full((len(sel), w), fill, dtype=dtype)
+                    a[r, p] = vals
+                    return a
+
+                gid = place(nb.pair_gid[src], -1, nb.pair_gid.dtype)
+                lev = place(nb.sim_level[src], 0, np.int8)
+                pm = place(True, False, bool)
+                uidx = place(_universe_index(universe, gid[r, p]), Np, np.int32)
+                si = place(ii[c], 0, np.int16)
+                sj = place(jj[c], 0, np.int16)
+            bt = _BinTensors(
+                entity_ids=pad_rows(nb.entity_ids[sel], -1, target),
+                entity_mask=pad_rows(nb.entity_mask[sel], False, target),
+                coauthor=pad_rows(nb.coauthor[sel], False, target),
+                sim_level=pad_rows(lev, 0, target),
+                pair_mask=pad_rows(pm, False, target),
+                uidx=pad_rows(uidx, Np, target),
+                pair_gid=pad_rows(gid, -1, target),
+                slot_i=pad_rows(si, 0, target),
+                slot_j=pad_rows(sj, 0, target),
+                rows=pad_rows(packed.bin_rows[k][sel], -1, target),
+            )
+            record_transfer(
+                "prepare", bt.entity_mask, bt.coauthor, bt.sim_level,
+                bt.pair_mask, bt.uidx, bt.pair_gid,
+            )
+            out[(k, w)] = bt
+    n = packed.num_neighborhoods
+    bin_of = np.zeros(n, dtype=np.int64)
+    row_of = np.zeros(n, dtype=np.int64)
+    for i, bt in enumerate(out.values()):
+        real = np.nonzero(bt.rows >= 0)[0]
+        bin_of[bt.rows[real]] = i
+        row_of[bt.rows[real]] = real
+    return _Staging(
+        bins=out, bin_of=bin_of, row_of=row_of, candidate_slots=candidate,
+        staged_slots=sum(bt.pair_mask.size for bt in out.values()),
+    )
 
 
 # ---------------------------------------------------------------------------
@@ -534,15 +648,17 @@ class FusedSpec:
     """Static shape/kind description of a fused multi-round program."""
 
     kinds: tuple[str, ...]  # per-bin matcher kind
-    ks: tuple[int, ...]
+    ks: tuple[int, ...]  # per-bin entity slots k
     batch: tuple[int, ...]  # per-bin padded batch size
-    num_pairs: tuple[int, ...]
+    num_pairs: tuple[int, ...]  # per-bin staged pair slots Pc
     universe_size: int
     history_cap: int = _HISTORY_CAP  # >= the largest budget ever passed
 
 
 def _eval_bin_x(kind: str, g, ev_pos, ev_neg):
-    """Batched matcher evaluation from cached grounding arrays."""
+    """Batched evaluation of the matchers that emit no messages, from
+    cached grounding arrays (the collective MLN runs in
+    :func:`_bin_full_round`)."""
     if kind == "rules":
         lev, n_shared, link, valid = g
         return rules_fixpoint_batch(lev, n_shared, link, ev_pos, ev_neg, valid)
@@ -552,9 +668,7 @@ def _eval_bin_x(kind: str, g, ev_pos, ev_neg):
     if kind == "embed":
         base, valid, _z0, _z1 = g
         return (base | ev_pos) & valid & ~ev_neg
-    u, u_raw, C, valid = g
-    x, _ = jax.vmap(_infer_one)(u, u_raw, C, ev_pos, ev_neg, valid)
-    return x
+    raise ValueError(f"no batched evaluation for kind {kind!r}")
 
 
 def _fused_rounds(spec: FusedSpec, axes: tuple[str, ...], *args):
@@ -834,9 +948,9 @@ class BinRoundSpec:
     """Static description of one bin's host-visible full round."""
 
     kind: str
-    k: int
+    k: int  # entity slots: k(k-1)/2 bounds the MLN's peel
     batch: int
-    num_pairs: int
+    num_pairs: int  # staged pair slots Pc
     universe_size: int
 
 
@@ -859,7 +973,8 @@ def _bin_full_round(spec: BinRoundSpec, axes, gather, g0, g1, g2, g3, uidx,
     ev_neg = jnp.zeros_like(ev_pos)
     g = (g0, g1, g2, g3)
     if spec.kind == "mln":
-        x, lab = jax.vmap(_infer_one)(g0, g1, g2, ev_pos, ev_neg, g3)
+        infer = functools.partial(_infer_one, num_pairs=pairlib.num_pairs(spec.k))
+        x, lab = jax.vmap(infer)(g0, g1, g2, ev_pos, ev_neg, g3)
     else:
         x = _eval_bin_x(spec.kind, g, ev_pos, ev_neg)
         lab = jnp.full(x.shape, spec.num_pairs, dtype=jnp.int32)
@@ -1158,8 +1273,9 @@ def _run_parallel_impl(
                 f"{base_kind!r} emits no multi-pair messages, so run_mmp "
                 "(sequential) or scheme='smp' reach the identical fixpoint"
             )
-        bins = _prepare_bins(packed, universe, pad_mult=n_shards)
-        bin_ks = sorted(bins)
+        staging = _prepare_bins(packed, universe, pad_mult=n_shards)
+        bins = staging.bins
+        bin_ks = list(bins)  # (k, Pc) keys, sorted
         dev_uidx = {
             k: kcommon.put_sharded(bins[k].uidx, mesh, axes) for k in bin_ks
         }
@@ -1174,20 +1290,23 @@ def _run_parallel_impl(
         m_plus = init_matches if init_matches is not None else MatchStore()
         m_bits = _seed_bits(universe, m_plus)
 
-    _rk_memo: dict[int, tuple | None] = {}
+    _rk_memo: dict[tuple, tuple | None] = {}
 
     def bin_row_keys(k):
         # packer row keys (streaming path) double as grounding
-        # fingerprints; padding rows get a stable sentinel
+        # fingerprints (a row's staged layout is a function of its
+        # content, and the bin key fixes its width); padding rows get a
+        # stable sentinel
         if packed.row_keys is None:
             return None
         if k not in _rk_memo:
-            real = tuple(packed.row_keys[int(n)] for n in packed.bin_rows[k])
-            pad = bins[k].entity_mask.shape[0] - len(real)
-            _rk_memo[k] = real + (("__pad__", k),) * pad
+            _rk_memo[k] = tuple(
+                packed.row_keys[n] if n >= 0 else ("__pad__", k)
+                for n in bins[k].rows.tolist()
+            )
         return _rk_memo[k]
 
-    run_grounds: dict[int, tuple] = {}
+    run_grounds: dict[tuple, tuple] = {}
 
     def ground_of(k):
         """Fetch one bin's grounded device arrays.
@@ -1214,7 +1333,7 @@ def _run_parallel_impl(
     # change, and grounding is deterministic, so the bounded-cache
     # re-fetch would be bit-identical anyway.
     spread = mesh.devices.size > 1
-    _global_grounds: dict[int, tuple] = {}
+    _global_grounds: dict[tuple, tuple] = {}
 
     def dispatch_grounds(k):
         if not spread:
@@ -1255,14 +1374,17 @@ def _run_parallel_impl(
     dispatches = 0
     history: list[int] = []
 
+    def rows_for(act_list):
+        """Staged bin index and row of each listed neighborhood."""
+        a = np.asarray(act_list, dtype=np.int64)
+        return staging.bin_of[a], staging.row_of[a]
+
     def masks_for(act_list):
-        masks = {
-            k: np.zeros(bins[k].entity_mask.shape[0], dtype=bool) for k in bin_ks
-        }
-        for n in act_list:
-            masks[int(packed.neighborhood_bin[n])][
-                int(packed.neighborhood_row[n])
-            ] = True
+        bi, ri = rows_for(act_list)
+        masks = {}
+        for i, k in enumerate(bin_ks):
+            masks[k] = np.zeros(bins[k].entity_mask.shape[0], dtype=bool)
+            masks[k][ri[bi == i]] = True
         return masks
 
     def live_rows(act_list):
@@ -1273,16 +1395,17 @@ def _run_parallel_impl(
         no-op in every driver.  Cost is O(|act_list| slots): only the
         requested rows are inspected, so a small dirty seed set stays
         cheap on a large corpus."""
+        bi, ri = rows_for(act_list)
         keep = []
-        for k, rows in packed.rows_for(act_list).items():
+        for i, k in enumerate(bin_ks):
+            rows = ri[bi == i]
             bt = bins[k]
             uidx = bt.uidx[rows]
             un = bt.pair_mask[rows] & (uidx < Np) & ~m_bits[
                 np.minimum(uidx, Np - 1)
             ]
-            live = np.asarray(rows)[un.any(axis=1)]
-            keep.extend(int(packed.bin_rows[k][r]) for r in live)
-        return sorted(keep)
+            keep.append(bt.rows[rows[un.any(axis=1)]])
+        return sorted(np.concatenate(keep).tolist())
 
     # round history buffer: one slot per possible round so EMResult
     # always has len(history) == rounds, whatever max_rounds the caller
@@ -1295,7 +1418,7 @@ def _run_parallel_impl(
             act_masks = masks_for(act_list)
             spec = FusedSpec(
                 kinds=tuple(kind for _ in bin_ks),
-                ks=tuple(bin_ks),
+                ks=tuple(k for k, _ in bin_ks),
                 batch=tuple(bins[k].entity_mask.shape[0] for k in bin_ks),
                 num_pairs=tuple(bins[k].pair_mask.shape[1] for k in bin_ks),
                 universe_size=Np,
@@ -1338,6 +1461,8 @@ def _run_parallel_impl(
             cache_evictions=gcache.evictions - evictions0,
             cold_regrounds=gcache.cold_regrounds - cold0,
             promote_host_scans=promoter.host_scans if promoter else 0,
+            candidate_slots=staging.candidate_slots,
+            staged_slots=staging.staged_slots,
         )
 
     collective = base_kind == "mln"
@@ -1361,7 +1486,7 @@ def _run_parallel_impl(
                     continue
                 spec = BinRoundSpec(
                     kind=base_kind,
-                    k=k,
+                    k=k[0],
                     batch=bins[k].entity_mask.shape[0],
                     num_pairs=bins[k].pair_mask.shape[1],
                     universe_size=Np,
@@ -1535,7 +1660,9 @@ def _run_parallel_legacy(
     Np = len(universe)
     if Np == 0:
         return _no_pairs_result(init_matches, t0)
-    bins = _prepare_bins(packed, universe)
+    # the full upper-triangle layout, as the cover packed it
+    uidx = {k: _universe_index(universe, nb.pair_gid)
+            for k, nb in packed.bins.items()}
 
     m_plus = init_matches if init_matches is not None else MatchStore()
     m_bits = _seed_bits(universe, m_plus)
@@ -1566,15 +1693,15 @@ def _run_parallel_legacy(
             and isinstance(matcher, MLNMatcher) and matcher.collective
         )
         for k, rows in sorted(packed.rows_for(active).items()):
-            bt = bins[k]
+            nb = packed.bins[k]
             sel = (
-                bt.entity_mask[rows],
-                bt.coauthor[rows],
-                bt.sim_level[rows],
-                bt.pair_mask[rows],
-                bt.uidx[rows],
+                nb.entity_mask[rows],
+                nb.coauthor[rows],
+                nb.sim_level[rows].astype(np.int8),
+                nb.pair_mask[rows],
+                uidx[k][rows],
             )
-            gid_rows = bt.pair_gid[rows]
+            gid_rows = nb.pair_gid[rows]
             n_rows = len(rows)
             padded = _pad_rows(list(sel), n_shards)
             spec = _matcher_spec(matcher, k, Np)

@@ -8,11 +8,13 @@ checks the kernels' results; this file checks that the chip accepts
 them at the shapes the main path uses:
 
 * ``icm_sweep`` / ``mln_score`` at every bin's pair count
-  (k = 8, 16, 24, 32 entities -> P = 28, 120, 276, 496);
+  (k = 8, 16, 24, 32 entities -> P = 28, 120, 276, 496) and at the
+  staged widths of compacted sub-bins (128, 256 slots);
 * ``minhash`` at a micro-batch and a bulk arrival count;
 * ``ngram_sim`` at the canopy probe (1 seed x pool) and an ingest probe;
 * the fused multi-round program and a full maximal-message round over
-  a small real cover, on a mesh of one described chip.
+  a small real cover, on a mesh of one described chip, and grounding
+  plus a full round of a 32-entity bin staged at 128 and 256 slots.
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and the tests run under
@@ -39,6 +41,7 @@ from repro.core.mln import PAPER_LEARNED
 from repro.kernels import common as kcommon
 
 BIN_PAIRS = [pairlib.num_pairs(k) for k in (8, 16, 24, 32)]  # 28 .. 496
+SUB_BIN_PAIRS = [128, 256]  # staged widths of compacted sub-bins
 
 
 @pytest.fixture(scope="module")
@@ -75,7 +78,7 @@ def _compiled_text(lowered) -> str:
     return lowered.compile().as_text()
 
 
-@pytest.mark.parametrize("P_", BIN_PAIRS)
+@pytest.mark.parametrize("P_", BIN_PAIRS + SUB_BIN_PAIRS)
 def test_icm_sweep_batch_compiles(one_chip, P_):
     from repro.kernels.icm_sweep import kernel
 
@@ -87,7 +90,7 @@ def test_icm_sweep_batch_compiles(one_chip, P_):
     assert "tpu_custom_call" in text
 
 
-@pytest.mark.parametrize("P_", BIN_PAIRS)
+@pytest.mark.parametrize("P_", BIN_PAIRS + SUB_BIN_PAIRS)
 def test_mln_score_sets_compiles(one_chip, P_):
     from repro.kernels.mln_score import kernel
 
@@ -127,14 +130,15 @@ def small_cover(hepth_small):
     """Per-bin argument shapes of the round programs over a real cover."""
     packed, _, _ = pipeline.prepare(hepth_small.entities, hepth_small.relations)
     universe = np.sort(np.asarray(sorted(packed.pair_levels), dtype=np.int64))
-    bins = par._prepare_bins(packed, universe)
+    staging = par._prepare_bins(packed, universe)
     ground = par._ground_bin_fn("mln", PAPER_LEARNED)
     out = {}
-    for k, bt in sorted(bins.items()):
+    for key, bt in staging.bins.items():
         g = jax.eval_shape(
-            ground, bt.entity_ids, bt.entity_mask, bt.coauthor, bt.sim_level, bt.pair_mask
+            ground, bt.entity_ids, bt.entity_mask, bt.coauthor, bt.sim_level,
+            bt.pair_mask, bt.slot_i, bt.slot_j,
         )
-        out[k] = (g, bt)
+        out[key] = (g, bt)
     return out, len(universe)
 
 
@@ -155,10 +159,10 @@ def test_fused_engine_compiles(chip_mesh, small_cover, monkeypatch):
     ``while_loop``, compiles for the chip at the cover's bin shapes."""
     monkeypatch.setattr(kcommon, "pallas_mode", lambda: "compiled")
     bins, Np = small_cover
-    ks = tuple(sorted(bins))
+    ks = tuple(bins)
     spec = par.FusedSpec(
         kinds=("mln_greedy",) * len(ks),
-        ks=ks,
+        ks=tuple(k for k, _ in ks),
         batch=tuple(bins[k][1].pair_mask.shape[0] for k in ks),
         num_pairs=tuple(bins[k][1].pair_mask.shape[1] for k in ks),
         universe_size=Np,
@@ -181,13 +185,44 @@ def test_full_round_compiles(chip_mesh, small_cover, monkeypatch):
     sweep runs ``icm_sweep`` over P seed rows) compiles for the chip."""
     monkeypatch.setattr(kcommon, "pallas_mode", lambda: "compiled")
     bins, Np = small_cover
-    k = max(bins)
-    g, bt = bins[k]
+    key = max(bins)
+    g, bt = bins[key]
     B, Pn = bt.pair_mask.shape
-    spec = par.BinRoundSpec(kind="mln", k=k, batch=B, num_pairs=Pn, universe_size=Np)
+    spec = par.BinRoundSpec(kind="mln", k=key[0], batch=B, num_pairs=Pn, universe_size=Np)
     fn = par.build_bin_round_fn(spec, chip_mesh, ("data",))
     rep = NamedSharding(chip_mesh, P())
     text = _compiled_text(fn.lower(
         *_bin_args(chip_mesh, g, bt), jax.ShapeDtypeStruct((Np,), jnp.bool_, sharding=rep)
+    ))
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("Pc", SUB_BIN_PAIRS)
+def test_sub_bin_ground_and_round_compile(chip_mesh, monkeypatch, Pc):
+    """A 32-entity bin staged at ``Pc`` compact slots: its grounding and
+    its maximal-message round compile for the chip at that width."""
+    monkeypatch.setattr(kcommon, "pallas_mode", lambda: "compiled")
+    k, B, Np = 32, 64, 4096
+    shd = NamedSharding(chip_mesh, P("data"))
+    rep = NamedSharding(chip_mesh, P())
+
+    def s(shape, dtype, sharding=shd):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    ground = jax.jit(par._ground_bin_fn("mln", PAPER_LEARNED))
+    rows = (
+        s((B, k), jnp.int64 if jax.config.jax_enable_x64 else jnp.int32),
+        s((B, k), jnp.bool_), s((B, k, k), jnp.bool_), s((B, Pc), jnp.int8),
+        s((B, Pc), jnp.bool_), s((B, Pc), jnp.int16), s((B, Pc), jnp.int16),
+    )
+    g = jax.eval_shape(ground, *rows)
+    assert [a.shape for a in g] == [(B, Pc), (B, Pc), (B, Pc, Pc), (B, Pc)]
+    ground.lower(*rows).compile()
+    spec = par.BinRoundSpec(kind="mln", k=k, batch=B, num_pairs=Pc, universe_size=Np)
+    fn = par.build_bin_round_fn(spec, chip_mesh, ("data",))
+    text = _compiled_text(fn.lower(
+        *[s(a.shape, a.dtype) for a in g],
+        s((B, Pc), jnp.int32), s((B, Pc), jnp.bool_), s((B,), jnp.bool_),
+        s((Np,), jnp.bool_, rep),
     ))
     assert "tpu_custom_call" in text
