@@ -37,6 +37,7 @@ from repro.core import pairs as pairlib
 from repro.core import similarity as simlib, txn
 from repro.core.types import EntityTable, NeighborhoodBatch, Relations
 from repro.kernels.ngram_sim import ops as sim_ops
+from repro.obs import span as obs_span
 from repro.obs.registry import get_registry
 
 DEFAULT_BINS = (8, 16, 24, 32)
@@ -71,6 +72,15 @@ class Cover:
         return self._entity_index
 
 
+# Seeds scored per similarity call of the canopy build.  A block row can
+# differ from the same row scored alone in the last float32 bits (the
+# matmul may sum in another order), so a seed whose row holds a cosine
+# within _TIE of a threshold that decides something is scored again
+# alone, as the one-seed-per-call construction scored it.
+_SEED_BLOCK = 256
+_TIE = 1e-5
+
+
 def build_canopies(
     features: np.ndarray,
     t_loose: float,
@@ -82,24 +92,54 @@ def build_canopies(
     keeps the construction reproducible.  Order-invariance of the *match
     output* is the framework's consistency property, tested separately.
 
-    The pool is uploaded once and each seed is probed against all of it
-    in one kernel call: per-seed host slices of the pool would move the
-    whole corpus to the device once per seed.
+    The pool is uploaded once.  The next ``_SEED_BLOCK`` remaining seeds
+    are probed against all of it in one kernel call, and the sequential
+    rule is replayed over the block's rows in id order: a seed that an
+    earlier seed of the same block suppressed is skipped (its row is
+    wasted).  The canopies equal the one-seed-per-call construction.
     """
     n = features.shape[0]
     remaining = np.ones(n, dtype=bool)
     canopies: list[np.ndarray] = []
     pool = jnp.asarray(features)
-    for seed in range(n):
-        if not remaining[seed]:
-            continue
-        sims = np.asarray(sim_ops.sim_above(features[seed : seed + 1], pool, 0.0))[0]
-        members = np.where(sims >= t_loose)[0]
-        if len(members) == 0:
-            members = np.array([seed])
-        canopies.append(members.astype(np.int64))
-        remaining[sims >= t_tight] = False
-        remaining[seed] = False
+    calls = 0
+
+    def probe(rows: np.ndarray) -> np.ndarray:
+        nonlocal calls
+        calls += 1
+        return np.asarray(sim_ops.sim_above(rows, pool, 0.0))
+
+    with obs_span("cover.canopies"):
+        start = 0
+        while True:
+            seeds = start + np.flatnonzero(remaining[start:])[:_SEED_BLOCK]
+            if not len(seeds):
+                break
+            block = np.zeros((_SEED_BLOCK, features.shape[1]), dtype=features.dtype)
+            block[: len(seeds)] = features[seeds]
+            sims = probe(block)[: len(seeds)]
+            # entries that can reach t_loose, row-major: each row's columns ascend
+            rows, cols = np.nonzero(sims >= t_loose - _TIE)
+            vals = sims[rows, cols]
+            bounds = np.searchsorted(rows, np.arange(len(seeds) + 1))
+            for r, seed in enumerate(seeds):
+                if not remaining[seed]:
+                    continue
+                c, v = cols[bounds[r] : bounds[r + 1]], vals[bounds[r] : bounds[r + 1]]
+                # a tie at t_tight decides only for a member still a seed
+                # candidate: every id below this seed is decided already
+                tie = (np.abs(v - t_loose) < _TIE) | ((np.abs(v - t_tight) < _TIE) & remaining[c])
+                if tie.any():
+                    row = probe(features[seed : seed + 1])[0]
+                    c, v = np.flatnonzero(row >= t_loose - _TIE), row[row >= t_loose - _TIE]
+                members = c[v >= t_loose]
+                if len(members) == 0:
+                    members = np.array([seed])
+                canopies.append(members.astype(np.int64))
+                remaining[c[v >= t_tight]] = False
+                remaining[seed] = False
+            start = int(seeds[-1]) + 1
+    get_registry().counter("cover.canopy_calls").inc(calls)
     return canopies
 
 
@@ -327,10 +367,9 @@ class PackedCover:
     pair_levels: dict[int, int]  # global gid -> sim level (>=1)
     cover: Cover
     # per-neighborhood row keys (bin, members, intra-relation edges) —
-    # populated when packing with a row_cache or via the CoverDelta
-    # splice path; the streaming path diffs them across ingests to find
-    # dirty neighborhoods, and the device GroundingCache fingerprints
-    # bin rows with them.
+    # populated by the CoverDelta splice path; the streaming path diffs
+    # them across ingests to find dirty neighborhoods, and the device
+    # GroundingCache fingerprints bin rows with them.
     row_keys: list[tuple] | None = None
     # splice-maintained incidence lookup, attached by the CoverDelta
     # path: (gid -> {row key: refcount}, entity -> {row key: refcount},
@@ -478,34 +517,42 @@ def _bin_of(size: int, k_bins: tuple[int, ...]) -> int:
     return next((kb for kb in k_bins if size <= kb), k_bins[-1])
 
 
-def _pair_level_fn(names: list[str], thresholds, level_cache: dict[int, int]):
-    """Host-side Jaro-Winkler discretization, memoized per global pair.
+def _pair_levels(
+    names: list[str], gids: np.ndarray, thresholds, level_cache: dict[int, int] | None
+) -> np.ndarray:
+    """Similarity level of each of the distinct pairs ``gids``
+    (:func:`repro.core.similarity.pair_levels`), through the optional
+    persistent memo ``level_cache`` (gid -> level) of the served path.
 
     Levels are name-static, so a cached entry can never go stale; the
     streaming layer may bound the memo (``DeltaCover.level_cache_max``)
     because a miss just recomputes from the strings.
     """
-
-    def pair_level(a: int, b: int) -> int:
-        gid = int(pairlib.make_gid(a, b))
-        lev = level_cache.get(gid)
-        if lev is None:
-            s = simlib.jaro_winkler(simlib.name_key(names[a]), simlib.name_key(names[b]))
-            lev = int(simlib.discretize(np.asarray([s]), thresholds)[0])
-            if lev == 0 and simlib.abbrev_compatible(names[a], names[b]):
-                lev = 1  # abbreviation-aware weak candidate
-            elif lev > 0 and simlib.first_name_conflict(names[a], names[b]):
-                lev = 0  # full first names of different people: veto
+    reg = get_registry()
+    with obs_span("cover.levels"):
+        out = np.zeros(len(gids), dtype=np.int8)
+        miss = np.ones(len(gids), dtype=bool)
+        if level_cache:
+            cached = np.fromiter(
+                (level_cache.get(g, -1) for g in gids.tolist()), dtype=np.int8, count=len(gids)
+            )
+            miss = cached < 0
+            out[~miss] = cached[~miss]
+        a, b = pairlib.split_gid(gids[miss])
+        lv, n_exact = simlib.pair_levels(names, a, b, thresholds)
+        out[miss] = lv
+        if level_cache is not None:
             t = txn.active()
-            if t is not None:
-                # gids index into `names`: an aborted ingest's entry could
-                # otherwise resolve to a *different* name pair after the
-                # ids are reused, caching a wrong level forever
-                t.save_key(level_cache, gid)
-            level_cache[gid] = lev
-        return lev
-
-    return pair_level
+            for g, v in zip(gids[miss].tolist(), lv.tolist()):
+                if t is not None:
+                    # gids index into `names`: an aborted ingest's entry could
+                    # otherwise resolve to a *different* name pair after the
+                    # ids are reused, caching a wrong level forever
+                    t.save_key(level_cache, g)
+                level_cache[g] = v
+    reg.counter("cover.level_pairs").inc(len(gids))
+    reg.counter("cover.level_exact").inc(n_exact)
+    return out
 
 
 def _row_key(members: np.ndarray, k: int, adj: dict[int, set[int]]) -> tuple:
@@ -522,52 +569,58 @@ def _row_key(members: np.ndarray, k: int, adj: dict[int, set[int]]) -> tuple:
     return (k, mkey, intra)
 
 
-def _stage_row(
-    members: np.ndarray, k: int, adj: dict[int, set[int]], pair_level
-) -> dict:
-    """Stage one neighborhood's padded row tensors (the per-row work of
-    :func:`pack_cover`, shared with the :class:`CoverDelta` splice path)."""
-    members = members[:k]  # safety clip (build_cover respects k_max)
-    P = pairlib.num_pairs(k)
-    ii, jj = pairlib.triu_indices(k)
+def _stage_bins(
+    fulls_by_bin: dict[int, list[np.ndarray]],
+    edge_keys: np.ndarray,
+    names: list[str],
+    thresholds,
+    level_cache: dict[int, int] | None = None,
+) -> dict[int, NeighborhoodBatch]:
+    """Stage the padded tensors of neighborhoods, one numpy pass per size
+    bin (the row staging of :func:`pack_cover`, shared with the
+    :class:`CoverDelta` splice path).
 
-    ids = np.full(k, -1, dtype=np.int64)
-    ids[: len(members)] = members
-    emask = ids >= 0
-    co = np.zeros((k, k), dtype=bool)
-    for a_slot in range(len(members)):
-        a = int(members[a_slot])
-        nbrs = adj.get(a, set())
-        for b_slot in range(a_slot + 1, len(members)):
-            if int(members[b_slot]) in nbrs:
-                co[a_slot, b_slot] = True
-                co[b_slot, a_slot] = True
+    ``fulls_by_bin`` maps a bin ``k`` to sorted member arrays,
+    ``edge_keys`` holds the sorted gids of the relation edges that may
+    join members.  The similarity level of every distinct member pair is
+    taken once (:func:`_pair_levels`) and scattered back into the slots.
+    """
+    slots = {}
+    for k, fulls in fulls_by_bin.items():
+        sizes = np.fromiter((min(len(m), k) for m in fulls), dtype=np.int64, count=len(fulls))
+        ids = np.full((len(fulls), k), -1, dtype=np.int64)
+        rows = np.repeat(np.arange(len(fulls)), sizes)
+        cols = np.arange(len(rows)) - np.repeat(np.cumsum(sizes) - sizes, sizes)
+        ids[rows, cols] = np.concatenate([m[:k] for m in fulls])
+        ii, jj = pairlib.triu_indices(k)
+        both = (ids[:, ii] >= 0) & (ids[:, jj] >= 0)
+        slots[k] = (ids, both, pairlib.make_gid(ids[:, ii], ids[:, jj]))
+    gids = np.unique(np.concatenate(
+        [pair[both] for _, both, pair in slots.values()] or [np.zeros(0, dtype=np.int64)]
+    ))
+    levels = _pair_levels(names, gids, thresholds, level_cache)
 
-    lev = np.zeros(P, dtype=np.int8)
-    gid = np.full(P, -1, dtype=np.int64)
-    pmask = np.zeros(P, dtype=bool)
-    for p in range(P):
-        i, j = int(ii[p]), int(jj[p])
-        if not (emask[i] and emask[j]):
-            continue
-        a, b = int(ids[i]), int(ids[j])
-        lv = pair_level(a, b)
-        if lv >= 1:
-            lev[p] = lv
-            gid[p] = pairlib.make_gid(a, b)
-            pmask[p] = True
-    return dict(ids=ids, emask=emask, co=co, lev=lev, gid=gid, pmask=pmask)
-
-
-def _stack_rows(rows: list[dict]) -> NeighborhoodBatch:
-    return NeighborhoodBatch(
-        entity_ids=np.stack([r["ids"] for r in rows]),
-        entity_mask=np.stack([r["emask"] for r in rows]),
-        coauthor=np.stack([r["co"] for r in rows]),
-        sim_level=np.stack([r["lev"] for r in rows]),
-        pair_gid=np.stack([r["gid"] for r in rows]),
-        pair_mask=np.stack([r["pmask"] for r in rows]),
-    )
+    out: dict[int, NeighborhoodBatch] = {}
+    for k, (ids, both, pair) in slots.items():
+        ii, jj = pairlib.triu_indices(k)
+        lev = np.zeros(pair.shape, dtype=np.int8)
+        lev[both] = levels[np.searchsorted(gids, pair[both])]
+        pmask = lev >= 1
+        co = np.zeros((len(ids), k, k), dtype=bool)
+        if len(edge_keys):
+            at = np.minimum(np.searchsorted(edge_keys, pair), len(edge_keys) - 1)
+            r, p = np.nonzero(both & (edge_keys[at] == pair))
+            co[r, ii[p], jj[p]] = True
+            co[r, jj[p], ii[p]] = True
+        out[k] = NeighborhoodBatch(
+            entity_ids=ids,
+            entity_mask=ids >= 0,
+            coauthor=co,
+            sim_level=lev,
+            pair_gid=np.where(pmask, pair, -1),
+            pair_mask=pmask,
+        )
+    return out
 
 
 def pack_cover(
@@ -579,22 +632,14 @@ def pack_cover(
     thresholds=simlib.DEFAULT_THRESHOLDS,
     boundary_relation: str = "coauthor",
     level_cache: dict[int, int] | None = None,
-    row_cache: dict[tuple, dict] | None = None,
     delta: "CoverDelta | None" = None,
     prev: "PackedCover | None" = None,
 ) -> PackedCover:
     """Pack a cover into size-binned padded tensors.
 
-    ``level_cache`` and ``row_cache`` are optional *persistent* caches
-    for the streaming path: ``level_cache`` memoizes the host-side
+    ``level_cache`` is an optional *persistent* memo of the host-side
     Jaro-Winkler discretization per global pair (a pure memo — the
-    streaming layer may bound it, see ``DeltaCover.level_cache_max``),
-    and ``row_cache`` memoizes fully staged neighborhood rows keyed by
-    ``(k, members, intra-relation edges)`` — a key that changes whenever
-    anything that feeds the row tensors changes, so stale entries can
-    never be reused.  Batch callers omit both and get the original
-    behavior; repacking after a micro-batch only stages rows for
-    new/changed neighborhoods ("repack only affected bins").
+    streaming layer may bound it, see ``DeltaCover.level_cache_max``).
 
     ``delta``/``prev`` select the incremental splice path: ``delta`` is
     the persistent :class:`CoverDelta` whose :meth:`CoverDelta.assemble`
@@ -605,51 +650,32 @@ def pack_cover(
     """
     if delta is not None:
         return delta.pack(cover, prev=prev, level_cache=level_cache)
-    adj = relations.adjacency_sets(boundary_relation)
-    if level_cache is None:
-        level_cache = {}
-    pair_level = _pair_level_fn(entities.names, thresholds, level_cache)
+    with obs_span("cover.pack"):
+        edges = np.asarray(relations.edges.get(boundary_relation, np.zeros((0, 2))), dtype=np.int64)
+        edges = edges.reshape(-1, 2)
+        edge_keys = np.unique(pairlib.make_gid(edges[:, 0], edges[:, 1]))
+        neighborhood_bin = np.fromiter(
+            (_bin_of(len(m), k_bins) for m in cover.full), dtype=np.int64, count=len(cover)
+        )
+        bin_rows = {k: np.flatnonzero(neighborhood_bin == k) for k in k_bins}
+        bin_rows = {k: rows for k, rows in bin_rows.items() if len(rows)}
+        neighborhood_row = np.zeros(len(cover), dtype=np.int64)
+        for rows in bin_rows.values():
+            neighborhood_row[rows] = np.arange(len(rows))
+        bins = _stage_bins(
+            {k: [cover.full[n] for n in rows] for k, rows in bin_rows.items()},
+            edge_keys, entities.names, thresholds, level_cache,
+        )
 
-    n_nb = len(cover)
-    neighborhood_bin = np.zeros(n_nb, dtype=np.int64)
-    neighborhood_row = np.zeros(n_nb, dtype=np.int64)
-    staged: dict[int, list[dict]] = {k: [] for k in k_bins}
-    row_keys: list[tuple] | None = [] if row_cache is not None else None
-
-    for n, members in enumerate(cover.full):
-        k = _bin_of(len(members), k_bins)
-
-        row = None
-        row_key = None
-        if row_cache is not None:
-            row_key = _row_key(members, k, adj)
-            row_keys.append(row_key)
-            row = row_cache.get(row_key)
-        if row is None:
-            row = _stage_row(members, k, adj, pair_level)
-            if row_cache is not None:
-                row_cache[row_key] = row
-
-        neighborhood_bin[n] = k
-        neighborhood_row[n] = len(staged[k])
-        staged[k].append(row)
-
-    bins: dict[int, NeighborhoodBatch] = {}
-    bin_rows: dict[int, np.ndarray] = {}
-    for k, rows in staged.items():
-        if not rows:
-            continue
-        bins[k] = _stack_rows(rows)
-        bin_rows[k] = np.where(neighborhood_bin == k)[0]
-
-    # pair_levels must reflect pairs co-resident in *this* cover — not the
-    # level cache, which on the streaming path persists across covers and
-    # would leak retracted candidate pairs into the global grounding.
-    pair_levels: dict[int, int] = {}
-    for rows in staged.values():
-        for r in rows:
-            for g, lv in zip(r["gid"][r["pmask"]], r["lev"][r["pmask"]]):
-                pair_levels[int(g)] = int(lv)
+        # pair_levels must reflect pairs co-resident in *this* cover — not
+        # the level cache, which on the streaming path persists across
+        # covers and would leak retracted candidate pairs into the global
+        # grounding.
+        pair_levels: dict[int, int] = {}
+        for nb in bins.values():
+            pair_levels.update(
+                zip(nb.pair_gid[nb.pair_mask].tolist(), nb.sim_level[nb.pair_mask].tolist())
+            )
     return PackedCover(
         bins=bins,
         bin_rows=bin_rows,
@@ -657,7 +683,6 @@ def pack_cover(
         neighborhood_row=neighborhood_row,
         pair_levels=pair_levels,
         cover=cover,
-        row_keys=row_keys,
     )
 
 
@@ -1334,20 +1359,29 @@ class CoverDelta:
                 t.save_attr(self, a)
         _, keys = self._pending
         self._pending = None
-        pair_level = _pair_level_fn(
-            self._names, self.thresholds, level_cache if level_cache is not None else {}
-        )
 
         # 1. stage rows for acquired keys not yet memoized (the O(dirty)
-        # work) — members are recoverable from the row key itself.
-        splice_rows = 0
-        for rk in self._acquires:
-            if rk not in self._rows:
-                members = np.asarray(rk[1], dtype=np.int64)
-                if t is not None:
-                    t.save_key(self._rows, rk)
-                self._rows[rk] = _stage_row(members, rk[0], self._adj, pair_level)
-                splice_rows += 1
+        # work) — members and intra-relation edges are recoverable from
+        # the row key itself.
+        fresh = [rk for rk in dict.fromkeys(self._acquires) if rk not in self._rows]
+        splice_rows = len(fresh)
+        if fresh:
+            by_bin: dict[int, list[tuple]] = {}
+            for rk in fresh:
+                by_bin.setdefault(rk[0], []).append(rk)
+            intra = [e for rk in fresh for e in rk[2]]
+            intra = np.asarray(intra, dtype=np.int64).reshape(-1, 2)
+            edge_keys = np.unique(pairlib.make_gid(intra[:, 0], intra[:, 1]))
+            staged = _stage_bins(
+                {k: [np.asarray(rk[1], dtype=np.int64) for rk in rks] for k, rks in by_bin.items()},
+                edge_keys, self._names, self.thresholds, level_cache,
+            )
+            for k, rks in by_bin.items():
+                nb = staged[k]
+                for i, rk in enumerate(rks):
+                    if t is not None:
+                        t.save_key(self._rows, rk)
+                    self._rows[rk] = {rf: getattr(nb, f)[i].copy() for f, rf in self._ROW_FIELDS}
 
         # 2. reference counting: batch-apply releases then acquires; a
         # key is *fresh* (dirty) iff it was absent from the previous

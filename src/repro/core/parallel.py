@@ -244,13 +244,13 @@ class GroundingCache:
     and an optional LRU bound on resident device memory.
 
     ``get`` fingerprints every row by the packer's row key when the
-    cover was packed with a ``row_cache`` (``PackedCover.row_keys`` —
+    cover came from the CoverDelta splice (``PackedCover.row_keys`` —
     the ``(k, members, intra-edges)`` tuple that by contract changes
     whenever anything feeding the row tensors changes; the streaming
     path always has these, so its per-ingest signature sweep is a tuple
     gather, not a serialization pass), falling back to a fixed-size
-    blake2b digest of the raw row bytes for covers packed without a
-    row cache.  An unchanged bin is served from cache outright; a bin
+    blake2b digest of the raw row bytes for covers packed from
+    scratch.  An unchanged bin is served from cache outright; a bin
     whose rows moved/changed is *spliced* — unchanged rows are gathered
     from the cached device arrays, only fresh rows are re-grounded (the
     O(B * P^2 * k) einsums), padded to a power of two to bound compile

@@ -158,13 +158,128 @@ def abbrev_compatible(a: str, b: str) -> bool:
     return abbrev and fa != fb
 
 
-def discretize(sim: np.ndarray, thresholds=DEFAULT_THRESHOLDS) -> np.ndarray:
+def similarity_level(a: str, b: str, thresholds=DEFAULT_THRESHOLDS) -> int:
+    """Level 0 (not a candidate) to 3 of one pair of names: the
+    discretized surname-first Jaro-Winkler, raised to 1 for an
+    abbreviation (:func:`abbrev_compatible`) and vetoed to 0 for two
+    different full first names (:func:`first_name_conflict`)."""
+    s = jaro_winkler(name_key(a), name_key(b))
     t1, t2, t3 = thresholds
-    lev = np.zeros(sim.shape, dtype=np.int8)
-    lev[sim >= t1] = 1
-    lev[sim >= t2] = 2
-    lev[sim >= t3] = 3
-    return lev
+    lev = 3 if s >= t3 else 2 if s >= t2 else 1 if s >= t1 else 0
+    if lev == 0:
+        return 1 if abbrev_compatible(a, b) else 0
+    return 0 if first_name_conflict(a, b) else lev
+
+
+# Slack of the Jaro-Winkler upper bound against the level-1 threshold:
+# float rounding in the bound's few operations is ~1e-16, so no pair
+# whose exact score reaches the threshold can be pruned.
+_BOUND_SLACK = 1e-9
+_HIST_BUCKETS = 64  # letter histogram columns; rarer letters share the last
+
+
+def pair_levels(
+    names: list[str], a: np.ndarray, b: np.ndarray, thresholds=DEFAULT_THRESHOLDS
+) -> tuple[np.ndarray, int]:
+    """:func:`similarity_level` of ``names[a[i]]`` and ``names[b[i]]``
+    for arrays of distinct pairs, and how many pairs needed the exact
+    Jaro-Winkler.
+
+    Most pairs of a cover are coauthors with unrelated names, and most
+    of the rest share a surname but not a first initial.  Neither needs
+    the scalar score:
+
+    - two names whose full first names differ in their initial have
+      level 0 whatever the score (below the thresholds no abbreviation
+      rule applies, above them the first-name veto does);
+    - Jaro similarity is at most ``(M/|x| + M/|y| + 1) / 3`` with ``M``
+      the overlap of the two keys' letter multisets (every matched
+      character is a common letter), and Winkler's boost is increasing
+      in it, with the pair's own common prefix.  A pair whose bound
+      stays under the lowest threshold has level 1 if one name
+      abbreviates the other (:func:`abbrev_compatible`), else 0.
+
+    Every other pair is scored exactly, once per distinct pair of name
+    strings, in ``(a, b)`` order.
+    """
+    a = np.asarray(a, dtype=np.int64)
+    b = np.asarray(b, dtype=np.int64)
+    out = np.zeros(len(a), dtype=np.int8)
+    if not len(a):
+        return out, 0
+    ids, inv = np.unique(np.concatenate([a, b]), return_inverse=True)
+    ia, ib = inv[: len(a)], inv[len(a) :]
+    strs = [names[int(i)] for i in ids]
+    keys = [name_key(s) for s in strs]
+    lens = np.fromiter((len(k) for k in keys), dtype=np.int64, count=len(keys))
+
+    # per name: surname, full first name and its initial (two-token names)
+    intern: dict[str, int] = {}
+    sid = np.full(len(strs), -1, dtype=np.int64)
+    fid = np.full(len(strs), -1, dtype=np.int64)
+    initial = np.full(len(strs), -1, dtype=np.int64)
+    flen = np.zeros(len(strs), dtype=np.int64)
+    for r, s in enumerate(strs):
+        t = s.lower().split()
+        first = t[0].rstrip(".") if len(t) >= 2 else ""
+        if first:
+            sid[r] = intern.setdefault("\0" + t[-1], len(intern))
+            fid[r] = intern.setdefault(first, len(intern))
+            initial[r], flen[r] = ord(first[0]), len(first)
+
+    letters: dict[str, int] = {}
+    for k in keys:
+        for ch in k:
+            letters[ch] = letters.get(ch, 0) + 1
+    common = sorted(letters, key=letters.get, reverse=True)[: _HIST_BUCKETS - 1]
+    col = {ch: c for c, ch in enumerate(common)}
+    other = len(common)  # merged letters still bound the overlap from above
+    hist = np.zeros((len(keys), other + 1), dtype=np.int16)
+    np.add.at(
+        hist,
+        (np.repeat(np.arange(len(keys)), lens),
+         np.fromiter((col.get(ch, other) for k in keys for ch in k), dtype=np.int64,
+                     count=int(lens.sum()))),
+        1,
+    )
+    head = np.full((len(keys), 4), -1, dtype=np.int64)
+    for r, k in enumerate(keys):
+        head[r, : min(4, len(k))] = [ord(ch) for ch in k[:4]]
+
+    exact = np.zeros(len(a), dtype=bool)
+    t_min = min(thresholds)
+    step = 1 << 18
+    for lo in range(0, len(a), step):
+        pa, pb = ia[lo : lo + step], ib[lo : lo + step]
+        m = np.minimum(hist[pa], hist[pb]).sum(axis=1).astype(np.float64)
+        la, lb = lens[pa].astype(np.float64), lens[pb].astype(np.float64)
+        with np.errstate(divide="ignore", invalid="ignore"):
+            jaro_ub = np.where(m > 0, (m / la + m / lb + 1.0) / 3.0, 0.0)
+        same = (head[pa] == head[pb]) & (head[pa] >= 0)
+        prefix = np.cumprod(same, axis=1).sum(axis=1)
+        jw_ub = jaro_ub + 0.1 * prefix * (1.0 - jaro_ub)
+        jw_ub[(la == 0) | (lb == 0)] = 1.0
+        both = (fid[pa] >= 0) & (fid[pb] >= 0)
+        vetoed = both & (initial[pa] != initial[pb])
+        abbrev = (both & (sid[pa] == sid[pb]) & (initial[pa] == initial[pb])
+                  & ((flen[pa] == 1) | (flen[pb] == 1)) & (fid[pa] != fid[pb]))
+        scored = ~vetoed & (jw_ub >= t_min - _BOUND_SLACK)
+        exact[lo : lo + step] = scored
+        out[lo : lo + step] = ~vetoed & ~scored & abbrev
+
+    at = np.flatnonzero(exact)
+    if len(at):
+        text: dict[str, int] = {}
+        nid = np.array([text.setdefault(s, len(text)) for s in strs], dtype=np.int64)
+        uniq, back = np.unique(nid[ia[at]] * len(text) + nid[ib[at]], return_inverse=True)
+        first = np.zeros(len(uniq), dtype=np.int64)
+        first[back] = at  # any pair of each distinct name pair
+        lv = np.array(
+            [similarity_level(names[int(a[i])], names[int(b[i])], thresholds) for i in first],
+            dtype=np.int8,
+        )
+        out[at] = lv[back]
+    return out, len(at)
 
 
 # ---------------------------------------------------------------------------

@@ -22,6 +22,7 @@ stable stage names are:
       ingest.lsh                MinHash/LSH probe (stream/delta._probe)
       ingest.replay             localized canopy replay
       ingest.cover_splice       incremental assemble + packed splice
+        cover.levels            similarity levels of the fresh rows' pairs
       ingest.grounding_splice   GroundingMaintainer delta + array splice
       ingest.rounds             fixpoint advance (engine.advance)
         em.run                  one run_parallel call (a root in batch use)
@@ -33,6 +34,9 @@ stable stage names are:
             rounds.messages     maximal messages from one bin's labels
           rounds.promote        step-7 promotion (device or host), whole
       ingest.commit             atomic cluster/fixpoint publish
+    cover.canopies              the batch canopy build (core/cover)
+    cover.pack                  the batch row staging, one pass per bin
+      cover.levels              similarity levels of the member pairs
     compile                     one XLA program load, under whatever
                                 span was open on the compiling thread
 
