@@ -46,6 +46,10 @@ class EMResult:
     peak_resident_bins: int = 0
     cache_evictions: int = 0
     cold_regrounds: int = 0
+    # Bin rows whose grounding-cache signature was taken by hashing
+    # (parallel engine): 0 when the cover has row keys or the run's
+    # cache never compares a bin against another staging of it.
+    rows_hashed: int = 0
     # Step-7 promotion passes that fell back to the host coupling-COO
     # walk (driver._promote).  The fused engine promotes on device
     # (parallel.DevicePromoter) and keeps this at 0 — gated in CI; the
@@ -72,6 +76,7 @@ _EM_COUNTER_FIELDS = (
     "messages_promoted",
     "cache_evictions",
     "cold_regrounds",
+    "rows_hashed",
     "promote_host_scans",
     "candidate_slots",
     "staged_slots",

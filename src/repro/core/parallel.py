@@ -13,11 +13,13 @@ boundary sits exactly at the *quiescence points*:
 * **Grounding cache** (:class:`GroundingCache`): the grounded structures
   (``u``/``u_raw``/``C``/``valid`` for the MLN, ``lev``/``n_shared``/
   ``link``/``valid`` for RULES) are computed once per ``(matcher, bin)``
-  and kept on device across rounds.  Rows are fingerprinted by the raw
-  bytes of the tensors the grounding reads, so the streaming engine
+  and kept on device across rounds.  Rows are fingerprinted by the
+  packer's row keys where the cover has them, so the streaming engine
   reuses cached bins across ingests and *splices* only the dirty rows'
   freshly grounded arrays into place (``rows_ground`` counts exactly the
-  recomputed rows).  Serving memory is boundable: an LRU over bins
+  recomputed rows); a batch cover's rows are hashed only when a cache
+  compares them against another staging of the bin
+  (``EMResult.rows_hashed``).  Serving memory is boundable: an LRU over bins
   (``capacity`` / ``hbm_budget_bytes``) drops cold bins' tensors and
   re-grounds them on demand, bit-for-bit (see the class docstring).
 
@@ -243,36 +245,51 @@ class GroundingCache:
     """Per-bin device-resident grounded structures with splice updates
     and an optional LRU bound on resident device memory.
 
-    ``get`` fingerprints every row by the packer's row key when the
-    cover came from the CoverDelta splice (``PackedCover.row_keys`` —
-    the ``(k, members, intra-edges)`` tuple that by contract changes
-    whenever anything feeding the row tensors changes; the streaming
-    path always has these, so its per-ingest signature sweep is a tuple
-    gather, not a serialization pass), falling back to a fixed-size
-    blake2b digest of the raw row bytes for covers packed from
-    scratch.  An unchanged bin is served from cache outright; a bin
-    whose rows moved/changed is *spliced* — unchanged rows are gathered
-    from the cached device arrays, only fresh rows are re-grounded (the
-    O(B * P^2 * k) einsums), padded to a power of two to bound compile
-    variants.  The streaming engine holds one cache per service so
-    ingests that leave a bin untouched never re-ground it; call
-    :meth:`invalidate` to drop everything (e.g. after changing matcher
-    weights in place).
+    Rows are fingerprinted by the packer's row key when the cover came
+    from the CoverDelta splice (``PackedCover.row_keys`` — the ``(k,
+    members, intra-edges)`` tuple that by contract changes whenever
+    anything feeding the row tensors changes; the streaming path always
+    has these, so its per-ingest signature sweep is a tuple gather, not
+    a serialization pass).  A cover packed from scratch has no row keys:
+    its entry keeps a reference to the staged bin (``_BinTensors``) it
+    was grounded from instead of signatures, and a ``get`` of that same
+    bin object is a hit outright (the per-dispatch re-fetch of a bounded
+    cache within one run).  Rows are hashed only when a ``get`` has to
+    compare a *different* bin object with an entry and a side has no row
+    keys: a fixed-size blake2b digest of each row's raw bytes, taken
+    once for the kept bin and once for the new one, after which the
+    entry holds the digests and drops the reference.  Identity stands for equal content because no
+    path writes into a staged bin's arrays once it is built:
+    ``_prepare_bins`` makes them fresh or shares the arrays of a batch
+    ``PackedCover``, which never changes after it is packed (the
+    CoverDelta's covers, whose rows are views of append buffers, carry
+    row keys, and an entry with row keys keeps no reference).
+
+    An unchanged bin is served from cache outright; a bin whose rows
+    moved/changed is *spliced* — unchanged rows are gathered from the
+    cached device arrays, only fresh rows are re-grounded (the O(B * P^2
+    * k) einsums), padded to a power of two to bound compile variants.
+    The streaming engine holds one cache per service so ingests that
+    leave a bin untouched never re-ground it; call :meth:`invalidate` to
+    drop everything (e.g. after changing matcher weights in place).
 
     **Serving-memory bound** (``capacity`` / ``hbm_budget_bytes``): the
     cached ``(B, P, P)`` coupling tensors dominate device memory, so a
     long-lived service can cap how many bins stay resident.  Entries
     are LRU-ordered by :meth:`get`; inserting past the bound drops the
-    coldest bins' device arrays (their row signatures are kept — host
-    tuples, not HBM).  A later ``get`` of an evicted bin *cold
-    re-grounds* it from the raw row tensors — grounding is a pure
-    function of those tensors, so the recomputed arrays are bit-for-bit
-    the evicted ones and every fixpoint is unchanged (tested under
-    capacities {1, 2, all}).  Eviction trades compute for memory only.
+    coldest bins' device arrays (their row signatures or bin reference
+    are kept — host objects, not HBM, and nothing is computed).  A later
+    ``get`` of an evicted bin *cold re-grounds* it from the raw row
+    tensors — grounding is a pure function of those tensors, so the
+    recomputed arrays are bit-for-bit the evicted ones and every
+    fixpoint is unchanged (tested under capacities {1, 2, all}).
+    Eviction trades compute for memory only.
 
     Counters (read by tests, ``EMResult`` and ``IngestReport``):
       ``ground_calls``        grounding dispatches issued
       ``rows_ground``         rows whose grounding was actually recomputed
+      ``rows_hashed``         rows whose signature was taken by hashing
+                              (row-key gathers do not count)
       ``bin_hits``            bins served without re-grounding any row
       ``splice_calls``        bins updated via :meth:`splice` (device scatter)
       ``evictions``           bins whose device arrays were LRU-dropped
@@ -291,11 +308,14 @@ class GroundingCache:
             )
         self.capacity = capacity
         self.hbm_budget_bytes = hbm_budget_bytes
-        # key -> (sigs, arrays | None, nbytes); dict order == LRU order
-        # (oldest first), arrays None for entries evicted but remembered
-        self._bins: dict[tuple, tuple[tuple, tuple | None, int]] = {}
+        # key -> (src, arrays | None, nbytes); src is the row signatures
+        # or, without row keys, the _BinTensors the arrays were grounded
+        # from; dict order == LRU order (oldest first), arrays None for
+        # entries evicted but remembered
+        self._bins: dict[tuple, tuple[tuple | _BinTensors, tuple | None, int]] = {}
         self.ground_calls = 0
         self.rows_ground = 0
+        self.rows_hashed = 0
         self.bin_hits = 0
         self.splice_calls = 0
         self.evictions = 0
@@ -323,7 +343,7 @@ class GroundingCache:
         self._bins.clear()
 
     _TXN_COUNTERS = (
-        "ground_calls", "rows_ground", "bin_hits", "splice_calls",
+        "ground_calls", "rows_ground", "rows_hashed", "bin_hits", "splice_calls",
         "evictions", "cold_regrounds", "peak_resident_bins",
         "peak_resident_bytes", "window_peak_bins",
     )
@@ -359,12 +379,12 @@ class GroundingCache:
     def _touch(self, key: tuple) -> None:
         self._bins[key] = self._bins.pop(key)
 
-    def _store(self, key: tuple, sigs: tuple, arrays: tuple) -> None:
+    def _store(self, key: tuple, src: tuple | _BinTensors, arrays: tuple) -> None:
         """Insert/refresh an entry as most-recent, then evict the coldest
         array-resident entries (never the one just stored) until the
         configured bin-count capacity and byte budget both hold."""
         self._bins.pop(key, None)
-        self._bins[key] = (sigs, arrays, self._nbytes(arrays))
+        self._bins[key] = (src, arrays, self._nbytes(arrays))
 
         def over() -> bool:
             if self.capacity is not None and self.resident_bins > self.capacity:
@@ -380,8 +400,8 @@ class GroundingCache:
                 k for k, (_, arrays, _) in self._bins.items()
                 if arrays is not None and k != key
             )
-            vsigs, _, _ = self._bins[victim]
-            self._bins[victim] = (vsigs, None, 0)
+            vsrc, _, _ = self._bins[victim]
+            self._bins[victim] = (vsrc, None, 0)
             # keep LRU position: an evicted entry stays coldest until re-used
             self.evictions += 1
         resident = self.resident_bins
@@ -391,10 +411,10 @@ class GroundingCache:
             self.peak_resident_bytes, self.resident_bytes
         )
 
-    @staticmethod
-    def _row_sigs(bt: _BinTensors, row_keys: tuple | None = None) -> tuple:
+    def _row_sigs(self, bt: _BinTensors, row_keys: tuple | None = None) -> tuple:
         if row_keys is not None:
             return row_keys
+        self.rows_hashed += bt.entity_mask.shape[0]
         return tuple(
             hashlib.blake2b(
                 bt.entity_ids[r].tobytes()
@@ -470,23 +490,32 @@ class GroundingCache:
     def get(self, matcher_key, bin_key: tuple, bt: _BinTensors,
             row_keys: tuple | None = None) -> tuple:
         key = (matcher_key, bin_key)
-        sigs = self._row_sigs(bt, row_keys)
         cached = self._bins.get(key)
-        if cached is not None and cached[0] == sigs and cached[1] is not None:
-            self.bin_hits += 1
-            self._touch(key)
-            return cached[1]
         if cached is None or cached[1] is None:
             # miss, or LRU-evicted arrays: (cold) re-ground every row —
             # grounding is pure in the row tensors, so this reproduces
-            # the dropped arrays bit-for-bit.
+            # the dropped arrays bit-for-bit.  Nothing is compared, so
+            # nothing is hashed: without row keys the entry keeps ``bt``.
             if cached is not None:
                 self.cold_regrounds += 1
             fn = _ground_bin_fn(*matcher_key)
-            arrays = self._ground_rows(fn, bt, np.arange(len(sigs)))
-        else:
-            arrays = self.splice(matcher_key, bt, sigs, (cached[0], cached[1]))
-        self._store(key, sigs, arrays)
+            arrays = self._ground_rows(fn, bt, np.arange(bt.entity_mask.shape[0]))
+            self._store(key, bt if row_keys is None else row_keys, arrays)
+            return arrays
+        src, arrays, nbytes = cached
+        if src is not bt:
+            sigs = self._row_sigs(bt, row_keys)
+            if isinstance(src, _BinTensors):
+                # first comparison against the kept bin: its digests
+                # replace the reference
+                src = self._row_sigs(src)
+                self._bins[key] = (src, arrays, nbytes)
+            if src != sigs:
+                arrays = self.splice(matcher_key, bt, sigs, (src, arrays))
+                self._store(key, sigs, arrays)
+                return arrays
+        self.bin_hits += 1
+        self._touch(key)
         return arrays
 
 
@@ -1347,6 +1376,7 @@ def _run_parallel_impl(
 
     evictions0 = gcache.evictions
     cold0 = gcache.cold_regrounds
+    hashed0 = gcache.rows_hashed
     gcache.begin_peak_window()
 
     # A fused dispatch passes EVERY bin's grounded tensors to one jitted
@@ -1460,6 +1490,7 @@ def _run_parallel_impl(
             peak_resident_bins=gcache.window_peak_bins,
             cache_evictions=gcache.evictions - evictions0,
             cold_regrounds=gcache.cold_regrounds - cold0,
+            rows_hashed=gcache.rows_hashed - hashed0,
             promote_host_scans=promoter.host_scans if promoter else 0,
             candidate_slots=staging.candidate_slots,
             staged_slots=staging.staged_slots,

@@ -121,6 +121,155 @@ def test_grounding_once_per_bin_per_cover(hepth_state, mln_paper):
     assert gcache.bin_hits == hits_before + n_bins
 
 
+def _staged_rows(packed) -> int:
+    """Rows over every bin the round engine stages for a cover."""
+    st = par._prepare_bins(packed, par._pair_universe(packed))
+    return sum(bt.entity_mask.shape[0] for bt in st.bins.values())
+
+
+def _em_counter(name: str) -> int:
+    return get_registry().snapshot()["counters"].get(name, 0)
+
+
+def _with_level_changed(packed):
+    """A copy of ``packed`` whose first k = 8 row has one candidate
+    pair's similarity level changed: one staged row's content differs."""
+    import dataclasses
+
+    nb = packed.bins[8]
+    lev = nb.sim_level.copy()
+    slot = int(np.nonzero(nb.pair_mask[0])[0][0])
+    lev[0, slot] = lev[0, slot] % 3 + 1
+    bins = dict(packed.bins)
+    bins[8] = dataclasses.replace(nb, sim_level=lev)
+    return dataclasses.replace(packed, bins=bins)
+
+
+@pytest.mark.parametrize("state", ["hepth_state", "split_state"])
+def test_batch_run_hashes_no_rows(request, state, mln_paper):
+    """A batch resolution with its own cache takes no row signature:
+    every bin is ground once and its entry keeps the staged bin, so
+    ``rows_hashed`` is 0 in the result and in ``em.rows_hashed``.  The
+    fixpoint is the sequential driver's, and matches, history and
+    message counts are those of a run whose cache compares every bin
+    by its hashed rows."""
+    packed, gg = request.getfixturevalue(state)[-2:]
+    before = _em_counter("em.rows_hashed")
+    res = run_parallel(packed, mln_paper, gg, scheme="mmp")
+    assert res.rows_hashed == 0
+    assert _em_counter("em.rows_hashed") == before
+
+    warm = GroundingCache()
+    run_parallel(packed, mln_paper, gg, scheme="mmp", gcache=warm)
+    hashed = run_parallel(packed, mln_paper, gg, scheme="mmp", gcache=warm)
+    assert hashed.rows_hashed == 2 * _staged_rows(packed)
+    assert res.matches.as_set() == run_mmp(packed, mln_paper, gg).matches.as_set()
+    assert res.matches.as_set() == hashed.matches.as_set()
+    assert res.history == hashed.history
+    assert (res.messages_emitted, res.messages_promoted) == (
+        hashed.messages_emitted, hashed.messages_promoted
+    )
+
+
+@pytest.mark.parametrize("changed", [False, True])
+def test_persistent_cache_hashes_only_to_compare(hepth_state, mln_paper,
+                                                 changed):
+    """A persistent cache across two batch calls sees new staged bin
+    objects on the second: it hashes the kept rows and the new rows
+    once each, serves unchanged bins whole and splices exactly the one
+    row whose content changed; the spliced cache resolves as a fresh
+    one does."""
+    packed, gg = hepth_state
+    gcache = GroundingCache()
+    first = run_parallel(packed, mln_paper, gg, scheme="mmp", gcache=gcache)
+    assert first.rows_hashed == 0 and gcache.rows_hashed == 0
+    n_bins, n_rows = _staged_bins(packed), _staged_rows(packed)
+    rows0, hits0 = gcache.rows_ground, gcache.bin_hits
+    splices0, cold0 = gcache.splice_calls, gcache.cold_regrounds
+
+    second = _with_level_changed(packed) if changed else packed
+    res = run_parallel(second, mln_paper, gg, scheme="mmp", gcache=gcache)
+    assert res.rows_hashed == gcache.rows_hashed == 2 * n_rows
+    assert gcache.rows_ground == rows0 + int(changed)
+    assert gcache.bin_hits == hits0 + n_bins - int(changed)
+    assert gcache.splice_calls == splices0 + int(changed)
+    assert gcache.cold_regrounds == cold0
+    fresh = run_parallel(second, mln_paper, gg, scheme="mmp")
+    assert res.matches.as_set() == fresh.matches.as_set()
+
+
+@pytest.mark.parametrize("cap", [1, 2])
+def test_bounded_cache_refetch_hashes_no_rows(hepth_state, mln_paper, cap):
+    """Spill mode re-fetches a bin at every dispatch within one run: the
+    same staged bin object is a hit outright, or a cold re-ground after
+    eviction, with no row hashed, and the fixpoint is unchanged."""
+    packed, gg = hepth_state
+    ref = run_parallel(packed, mln_paper, gg, scheme="mmp")
+    gcache = GroundingCache(capacity=cap)
+    res = run_parallel(packed, mln_paper, gg, scheme="mmp", gcache=gcache)
+    assert res.matches.as_set() == ref.matches.as_set()
+    assert res.rows_hashed == 0 and gcache.rows_hashed == 0
+    assert res.cold_regrounds > 0
+
+    st = par._prepare_bins(packed, par._pair_universe(packed))
+    key, bt = next(iter(st.bins.items()))
+    mkey = par._matcher_cache_key(mln_paper)
+    own = GroundingCache(capacity=cap)
+    arrays = own.get(mkey, key, bt)
+    assert own.get(mkey, key, bt) is arrays
+    assert (own.bin_hits, own.ground_calls, own.rows_hashed) == (1, 1, 0)
+
+
+def test_rollback_after_lazy_hash_restores_cache(hepth_state, mln_paper):
+    """``journal_rollback`` snapshots entries shallowly: after a call
+    that replaced kept bins by their digests, a rollback restores the
+    entries (the same objects) and every counter exactly, and the next
+    call hashes again as the rolled-back one did."""
+    from repro.core import txn
+
+    packed, gg = hepth_state
+    gcache = GroundingCache()
+    run_parallel(packed, mln_paper, gg, scheme="mmp", gcache=gcache)
+    entries = dict(gcache._bins)
+    assert all(isinstance(e[0], par._BinTensors) for e in entries.values())
+    counters = {c: getattr(gcache, c) for c in GroundingCache._TXN_COUNTERS}
+
+    t = txn.Transaction()
+    gcache.journal_rollback(t)
+    res = run_parallel(packed, mln_paper, gg, scheme="mmp", gcache=gcache)
+    assert res.rows_hashed > 0
+    assert all(isinstance(e[0], tuple) for e in gcache._bins.values())
+    t.rollback()
+    assert gcache._bins.keys() == entries.keys()
+    assert all(gcache._bins[k] is e for k, e in entries.items())
+    assert {c: getattr(gcache, c) for c in GroundingCache._TXN_COUNTERS} == counters
+    again = run_parallel(packed, mln_paper, gg, scheme="mmp", gcache=gcache)
+    assert again.rows_hashed == res.rows_hashed
+    assert again.matches.as_set() == res.matches.as_set()
+
+
+def test_stream_ingest_with_row_keys_hashes_no_rows():
+    """The served path's covers carry row keys: ingests compare bins by
+    them (hits and splices) and hash no row."""
+    from repro.stream import ResolveService, ServiceConfig
+
+    before = _em_counter("em.rows_hashed")
+    svc = ResolveService(ServiceConfig(scheme="smp", parallel=True))
+    covers = []
+    advance = svc.engine.advance
+    svc.engine.advance = lambda packed, *a, **kw: (
+        covers.append(packed) or advance(packed, *a, **kw)
+    )
+    svc.ingest([f"alessandro brunelleschi{chr(97 + i)}" for i in range(10)])
+    g = svc.engine.gcache
+    compared = g.bin_hits + g.splice_calls
+    svc.ingest([f"evangelina montgomery{chr(97 + i)}" for i in range(5)])
+    assert len(covers) == 2 and all(c.row_keys is not None for c in covers)
+    assert g.bin_hits + g.splice_calls > compared
+    assert g.rows_hashed == 0
+    assert _em_counter("em.rows_hashed") == before
+
+
 def test_fused_dispatch_counts(hepth_state, mln_paper):
     """Dispatch accounting of the device-resident engine: a cheap
     (greedy/rules) matcher's whole multi-round closure is ONE host
