@@ -12,7 +12,7 @@ invocation per baseline file) runs every gate that applies to the file,
   - ``dispatch``: the ``dispatches_per_round`` of every scheme, bounded
     by O(bins + quiescence points) per round with the bin count capped
     by ``DEFAULT_BINS``, so a smoke-scale run is comparable to the
-    committed default-scale baseline.  A regression to the legacy
+    committed default-scale baseline.  A regression to an
     O(bins x rounds) dispatch pattern blows well past the slack.
   - ``promotion``: ``promote_host_scans`` of the fused engine must be
     exactly 0 — step-7 delta checks run batched on device
@@ -83,7 +83,7 @@ import sys
 from typing import Callable
 
 # Multiplicative + additive slack: quiescence-point counts can shift by
-# a round or two between corpus scales; a true regression to the legacy
+# a round or two between corpus scales; a true regression to an
 # O(bins x rounds) dispatch pattern blows well past this.
 REL_SLACK = 1.5
 ABS_SLACK = 2.0

@@ -19,10 +19,9 @@ Two instances are measured:
   where the per-round host overhead the device-resident engine removes
   (re-grounding, per-bin dispatch, active-set bookkeeping) dominates.
 
-Each scheme runs twice: the fused device-resident engine (cached
-groundings, multi-round ``while_loop`` closure — the default) and the
-legacy per-round host loop (``fused=False``).  ``speedup_vs_legacy`` is
-the wall-time ratio; ``dispatches_per_round`` is the host-dispatch
+Each scheme runs on the device-resident engine (cached groundings,
+multi-round ``while_loop`` closure) and its fixpoint is checked against
+the sequential driver's.  ``dispatches_per_round`` is the host-dispatch
 metric the CI smoke gate tracks against the committed
 ``BENCH_parallel.json``.
 """
@@ -33,6 +32,7 @@ import numpy as np
 
 from benchmarks.common import SMOKE, prepared, row, timed
 from repro import obs
+from repro.core.driver import run_mmp, run_nomp, run_smp
 from repro.core.global_grounding import build_global_grounding
 from repro.core.mln import MLNMatcher
 from repro.core.parallel import run_parallel
@@ -72,63 +72,52 @@ def _measure(name: str, packed, gg, matcher, schemes) -> dict:
     }
     row(f"# table1[{name}]: parallel rounds (SPMD mesh; model for 30 shards)")
     row(
-        "scheme,wall_fused_s,wall_legacy_s,speedup_vs_legacy,rounds,evals,"
-        "dispatches,dispatches_legacy,dispatches_per_round,"
+        "scheme,wall_s,rounds,evals,dispatches,dispatches_per_round,"
         "promote_host_scans,modeled_speedup_30"
     )
+    sequential = {
+        "nomp": lambda: run_nomp(packed, matcher),
+        "smp": lambda: run_smp(packed, matcher),
+        "mmp": lambda: run_mmp(packed, matcher, gg),
+    }
     for scheme in schemes:
         # Each run gets its own registry window: run_parallel publishes
         # its EMResult as cumulative ``em.*`` counters, so the bench
-        # reads one snapshot per engine instead of dataclass fields.
+        # reads one snapshot instead of dataclass fields.
         obs.reset()
-        legacy, t_legacy = timed(
-            lambda s=scheme: run_parallel(packed, matcher, gg, scheme=s,
-                                          fused=False)
-        )
-        c_legacy = obs.get_registry().snapshot()["counters"]
-        obs.reset()
-        res, t_fused = timed(
+        res, wall = timed(
             lambda s=scheme: run_parallel(packed, matcher, gg, scheme=s)
         )
-        c_fused = obs.get_registry().snapshot()["counters"]
-        assert res.matches.as_set() == legacy.matches.as_set(), (name, scheme)
-        rounds = c_fused.get("em.rounds", 0)
-        evals = c_fused.get("em.neighborhood_evals", 0)
-        dispatches = c_fused.get("em.dispatches", 0)
-        host_scans = c_fused.get("em.promote_host_scans", 0)
+        counters = obs.get_registry().snapshot()["counters"]
+        seq = sequential[scheme]().matches.as_set()
+        assert res.matches.as_set() == seq, (name, scheme)
+        rounds = counters.get("em.rounds", 0)
+        evals = counters.get("em.neighborhood_evals", 0)
+        dispatches = counters.get("em.dispatches", 0)
+        host_scans = counters.get("em.promote_host_scans", 0)
         hist = res.history or [packed.num_neighborhoods]
-        sp = skew_speedup(packed, hist, 30, overhead_s=0.05 * t_fused,
-                          t_total=t_fused)
+        sp = skew_speedup(packed, hist, 30, overhead_s=0.05 * wall,
+                          t_total=wall)
         dpr = dispatches / max(rounds, 1)
         row(
             scheme,
-            f"{t_fused:.3f}",
-            f"{t_legacy:.3f}",
-            f"{t_legacy / max(t_fused, 1e-9):.1f}x",
+            f"{wall:.3f}",
             rounds,
             evals,
             dispatches,
-            c_legacy.get("em.dispatches", 0),
             f"{dpr:.2f}",
             host_scans,
             f"{sp:.1f}",
         )
         out["schemes"][scheme] = {
-            "wall_s": round(t_fused, 4),
-            "wall_legacy_s": round(t_legacy, 4),
-            "speedup_vs_legacy": round(t_legacy / max(t_fused, 1e-9), 2),
+            "wall_s": round(wall, 4),
             "rounds": int(rounds),
             "evals": int(evals),
             "dispatches": int(dispatches),
-            "dispatches_legacy": int(c_legacy.get("em.dispatches", 0)),
             "dispatches_per_round": round(dpr, 3),
             # host coupling-COO promotion walks of the fused engine —
-            # device-resident promotion keeps this 0 (gated in CI); the
-            # legacy loop's count shows what the host baseline pays
+            # device-resident promotion keeps this 0 (gated in CI)
             "promote_host_scans": int(host_scans),
-            "promote_host_scans_legacy": int(
-                c_legacy.get("em.promote_host_scans", 0)
-            ),
         }
     return out
 

@@ -393,15 +393,6 @@ class PackedCover:
     def num_neighborhoods(self) -> int:
         return len(self.neighborhood_bin)
 
-    def rows_for(self, neighborhoods: list[int]) -> dict[int, np.ndarray]:
-        """Group a set of neighborhood ids by bin -> row arrays."""
-        out: dict[int, list[int]] = {}
-        for n in neighborhoods:
-            out.setdefault(int(self.neighborhood_bin[n]), []).append(
-                int(self.neighborhood_row[n])
-            )
-        return {k: np.asarray(v, dtype=np.int64) for k, v in out.items()}
-
     def _positions_of_entity(self, e: int) -> set[int]:
         """Neighborhood positions whose full membership holds ``e``
         (splice-lookup path; callers guard on ``slot_lookup``)."""

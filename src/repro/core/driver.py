@@ -53,8 +53,8 @@ class EMResult:
     # Step-7 promotion passes that fell back to the host coupling-COO
     # walk (driver._promote).  The fused engine promotes on device
     # (parallel.DevicePromoter) and keeps this at 0 — gated in CI; the
-    # legacy fused=False loop and the sequential run_mmp count every
-    # pass here by design (they ARE the host baseline).
+    # sequential run_mmp counts every pass here by design (it IS the
+    # host baseline).
     promote_host_scans: int = 0
     # Pair slots of the round engine's staged bins (parallel engine):
     # the candidate pairs over every neighborhood, and the slots staged

@@ -31,8 +31,8 @@ boundary sits exactly at the *quiescence points*:
   ``staged_slots`` count both).
 
 * **Fused multi-round closure** (:func:`build_fused_fn`): rounds that
-  touch no host state — all NO-MP/SMP rounds, and MMP's ``fast_rounds``
-  greedy re-activation rounds — run inside a single jitted
+  touch no host state — all NO-MP/SMP rounds, and the greedy
+  re-activation rounds of SMP and MMP — run inside a single jitted
   ``jax.lax.while_loop``.  The loop body evaluates every bin (batched,
   ``shard_map``-sharded over the mesh's data axes), ORs the matched
   pairs into a replicated match bitset (one ``psum`` per round — the
@@ -59,14 +59,12 @@ a non-incident neighborhood is idempotent (its evidence projection is
 unchanged), and deferring step-7 promotion to quiescence points
 composes monotone operators whose least fixpoint is schedule-invariant.
 ``tests/test_parallel_rounds.py`` asserts bit-for-bit equality for all
-three schemes, ``fast_rounds`` on and off, against both the sequential
-drivers and the legacy per-round host loop (kept under ``fused=False``
-as the differential baseline that ``benchmarks/table1_parallel.py``
-measures the speedup against).
+three schemes, and for a spill-mode cache, against the sequential
+drivers (``run_nomp``/``run_smp``/``run_mmp``), the one reference.
 
-The per-round SPMD function is exposed via :func:`build_round_fn` so the
-multi-pod dry-run can ``.lower().compile()`` the EM round on the
-production mesh.
+The mesh dry-run (``launch/dryrun.py``) lowers the programs the engine
+dispatches: a bin's grounding (:func:`_ground_bin_fn`) and its full
+round (:func:`build_bin_round_fn`) on the production mesh.
 """
 
 from __future__ import annotations
@@ -95,14 +93,13 @@ from repro.obs import record_transfer
 from repro.obs import span as obs_span
 from repro.core.global_grounding import GlobalGrounding
 from repro.core.mln import (
-    MLNMatcher,
     MLNWeights,
     _infer_one,
     closure_batch,
     ground,
     ground_structure,
 )
-from repro.core.rules import _rules_fixpoint, rules_fixpoint_batch
+from repro.core.rules import rules_fixpoint_batch
 from repro.core.types import MatchStore, NeighborhoodBatch
 from repro.kernels import common as kcommon
 
@@ -1044,115 +1041,6 @@ def build_bin_round_fn(spec: BinRoundSpec, mesh: Mesh, axes: tuple[str, ...]):
 
 
 # ---------------------------------------------------------------------------
-# Legacy per-round host loop (build_round_fn stays for the mesh dry-run)
-# ---------------------------------------------------------------------------
-
-
-@dataclasses.dataclass(frozen=True)
-class RoundSpec:
-    """Static description of one bin's round function."""
-
-    k: int
-    num_pairs: int
-    universe_size: int
-    matcher_kind: str  # 'mln' | 'mln_greedy' | 'rules'
-    weights: MLNWeights | None
-
-
-def _device_round(spec: RoundSpec, axes: tuple[str, ...], entity_mask, coauthor,
-                  sim_level, pair_mask, uidx, m_bits):
-    """One shard's work for one legacy round: re-grounds from the raw
-    tensors on every call (the per-round overhead the grounding cache
-    and fused engine remove — kept as the differential baseline)."""
-    Np = spec.universe_size
-    safe = jnp.minimum(uidx, Np - 1)
-    ev_pos = m_bits[safe] & (uidx < Np) & pair_mask
-    ev_neg = jnp.zeros_like(ev_pos)
-
-    batch = NeighborhoodBatch(
-        entity_ids=entity_mask,  # only shapes/masks are used by grounding
-        entity_mask=entity_mask,
-        coauthor=coauthor,
-        sim_level=sim_level,
-        pair_gid=uidx,
-        pair_mask=pair_mask,
-    )
-    if spec.matcher_kind == "rules":
-        lev, valid, n_shared, link = ground_structure(batch)
-        x = jax.vmap(_rules_fixpoint)(lev, n_shared, link, ev_pos, ev_neg, valid)
-        lab = jnp.full(x.shape, spec.num_pairs, dtype=jnp.int32)
-    else:
-        g = ground(batch, spec.weights)
-        if spec.matcher_kind == "mln_greedy":
-            x = closure_batch(g.u, g.C, ev_pos, ev_neg, g.valid)
-            lab = jnp.full(x.shape, spec.num_pairs, dtype=jnp.int32)
-        else:
-            x, lab = jax.vmap(_infer_one)(g.u, g.u_raw, g.C, ev_pos, ev_neg,
-                                          g.valid)
-
-    flat_idx = uidx.reshape(-1)
-    flat_val = (x & pair_mask).reshape(-1)
-    local_bits = jnp.zeros((Np,), jnp.int32).at[flat_idx].max(
-        flat_val.astype(jnp.int32), mode="drop"
-    )
-    bits = local_bits
-    for ax in axes:
-        bits = jax.lax.psum(bits, ax)
-    return x, lab, (bits > 0) | m_bits
-
-
-@functools.lru_cache(maxsize=None)
-def build_round_fn(spec: RoundSpec, mesh: Mesh, axes: tuple[str, ...]):
-    """Jitted SPMD round function for one (bin, mesh) combination."""
-    batch_spec = P(axes)
-    rep = P()
-    fn = functools.partial(_device_round, spec, axes)
-    mapped = jax.shard_map(
-        fn,
-        mesh=mesh,
-        in_specs=(batch_spec, batch_spec, batch_spec, batch_spec, batch_spec, rep),
-        out_specs=(batch_spec, batch_spec, rep),
-        check_vma=False,
-    )
-    return jax.jit(mapped)
-
-
-def _matcher_spec(matcher, k: int, Np: int) -> RoundSpec:
-    kind, weights = _matcher_cache_key(matcher)
-    if kind not in ("mln", "rules"):
-        raise TypeError(
-            f"legacy per-round loop supports only the jit-groundable "
-            f"'mln'/'rules' kinds, got {kind!r}; use the fused engine"
-        )
-    if kind == "mln" and not getattr(matcher, "collective", True):
-        kind = "mln_greedy"
-    return RoundSpec(
-        k=k,
-        num_pairs=pairlib.num_pairs(k),
-        universe_size=Np,
-        matcher_kind=kind,
-        weights=weights,
-    )
-
-
-def _pad_rows(arrs: list[np.ndarray], mult: int) -> list[np.ndarray]:
-    """Pad the batch axis to a multiple of the shard count.
-
-    Padding rows are all-zero: ``pair_mask`` False everywhere makes them
-    inert (no candidate pairs, no scatters — `x & pair_mask` is False).
-    """
-    b = arrs[0].shape[0]
-    target = max(((b + mult - 1) // mult) * mult, mult)
-    if target == b:
-        return arrs
-    out = []
-    for a in arrs:
-        pad = np.zeros((target - b,) + a.shape[1:], dtype=a.dtype)
-        out.append(np.concatenate([a, pad], axis=0))
-    return out
-
-
-# ---------------------------------------------------------------------------
 # Drivers
 # ---------------------------------------------------------------------------
 
@@ -1196,12 +1084,10 @@ def run_parallel(
     scheme: str = "smp",
     mesh: Mesh | None = None,
     max_rounds: int = 256,
-    fast_rounds: bool = True,
     active: list[int] | None = None,
     init_matches: MatchStore | None = None,
     pool: MessagePool | None = None,
     gcache: GroundingCache | None = None,
-    fused: bool = True,
 ) -> EMResult:
     """Round-parallel NO-MP / SMP / MMP over the mesh's data axes.
 
@@ -1213,9 +1099,8 @@ def run_parallel(
     with obs_span("em.run", scheme=scheme):
         res = _run_parallel_impl(
             packed, matcher, gg, scheme=scheme, mesh=mesh,
-            max_rounds=max_rounds, fast_rounds=fast_rounds, active=active,
+            max_rounds=max_rounds, active=active,
             init_matches=init_matches, pool=pool, gcache=gcache,
-            fused=fused,
         )
         return publish_em_result(res)
 
@@ -1228,12 +1113,10 @@ def _run_parallel_impl(
     scheme: str = "smp",
     mesh: Mesh | None = None,
     max_rounds: int = 256,
-    fast_rounds: bool = True,
     active: list[int] | None = None,
     init_matches: MatchStore | None = None,
     pool: MessagePool | None = None,
     gcache: GroundingCache | None = None,
-    fused: bool = True,
 ) -> EMResult:
     """Round-parallel NO-MP / SMP / MMP over the mesh's data axes.
 
@@ -1257,21 +1140,17 @@ def _run_parallel_impl(
     cold bins re-ground on demand — same fixpoint bit-for-bit, compute
     traded for bounded HBM.
 
-    ``fast_rounds`` (SMP and MMP with the collective MLN): re-activation
-    rounds run the *greedy closure* variant — evidence-driven
-    propagation needs no entailment matrix, which is the entire O(P^3)
-    cost of a full round (measured 3376x cheaper per round on the
-    production-mesh dry-run).  With the fused engine those greedy
-    rounds run inside a single on-device ``while_loop``; a full round
+    SMP and MMP with the collective MLN run re-activation rounds as the
+    *greedy closure* variant — evidence-driven propagation needs no
+    entailment matrix, which is the entire O(P^3) cost of a full round —
+    inside a single on-device ``while_loop``; a full round
     (maximal-message inference for MMP, full collective MAP for SMP)
     runs first and again at every quiescence point, so the final
     fixpoint is closed under the full matcher on every neighborhood:
     greedy closure under evidence is sound (Prop. 6), and termination
     still requires a full round to have produced nothing new (Thm. 2/4).
-
-    ``fused=False`` selects the legacy per-round host loop (one dispatch
-    per bin per round, re-grounding every time) — the differential
-    baseline for tests and ``benchmarks/table1_parallel.py``.
+    In spill mode (a cache bounded below the bin count, or by bytes)
+    every round is a full round.
     """
     t0 = time.perf_counter()
     if scheme == "mmp":
@@ -1279,13 +1158,6 @@ def _run_parallel_impl(
     mesh = mesh or make_em_mesh()
     axes = tuple(mesh.axis_names)
     n_shards = int(np.prod(mesh.devices.shape))
-
-    if not fused:
-        return _run_parallel_legacy(
-            packed, matcher, gg, scheme=scheme, mesh=mesh,
-            max_rounds=max_rounds, fast_rounds=fast_rounds, active=active,
-            init_matches=init_matches, pool=pool, t0=t0, n_shards=n_shards,
-        )
 
     with obs_span("rounds.stage"):
         universe = _pair_universe(packed)
@@ -1588,12 +1460,11 @@ def _run_parallel_impl(
     # Re-activation rounds only propagate evidence, so they run as
     # greedy closure inside the fused device loop; a full round over
     # every neighborhood runs at each quiescence point (and first), so
-    # the fixpoint is closed under the full matcher — the same soundness
-    # argument as MMP's fast_rounds (Prop. 6 + Thm. 2/4), now shared by
-    # SMP.  Spill mode disables the fused segments outright (they stage
-    # every bin at once): each round is per-bin full dispatches, the
+    # the fixpoint is closed under the full matcher (Prop. 6 + Thm. 2/4).
+    # Spill mode disables the fused segments outright (they stage every
+    # bin at once): each round is per-bin full dispatches, the
     # memory-for-dispatches trade of a bounded cache.
-    greedy_ok = fast_rounds and collective and not spill_mode
+    greedy_ok = collective and not spill_mode
     full_round = True
     seeds = list(active)
     bits0 = m_bits.copy()
@@ -1665,133 +1536,3 @@ def _run_parallel_impl(
         if greedy_ok and active:
             full_round = False
     return finish()
-
-
-def _run_parallel_legacy(
-    packed: PackedCover,
-    matcher,
-    gg: GlobalGrounding | None,
-    *,
-    scheme: str,
-    mesh: Mesh,
-    max_rounds: int,
-    fast_rounds: bool,
-    active: list[int] | None,
-    init_matches: MatchStore | None,
-    pool: MessagePool | None,
-    t0: float,
-    n_shards: int,
-) -> EMResult:
-    """The pre-fusion host round loop: one dispatch per bin per round,
-    re-grounding from raw tensors every time, per-row message walks.
-    Kept as the differential baseline (tests assert bit-for-bit equality
-    with the fused engine; ``table1_parallel`` reports the speedup)."""
-    axes = tuple(mesh.axis_names)
-    universe = _pair_universe(packed)
-    Np = len(universe)
-    if Np == 0:
-        return _no_pairs_result(init_matches, t0)
-    # the full upper-triangle layout, as the cover packed it
-    uidx = {k: _universe_index(universe, nb.pair_gid)
-            for k, nb in packed.bins.items()}
-
-    m_plus = init_matches if init_matches is not None else MatchStore()
-    m_bits = _seed_bits(universe, m_plus)
-    if pool is None:
-        pool = MessagePool()
-    active = (
-        list(active) if active is not None else list(range(packed.num_neighborhoods))
-    )
-    evals = 0
-    emitted = 0
-    promoted_total = 0
-    rounds = 0
-    dispatches = 0
-    host_scans = 0
-    history: list[int] = []
-
-    # MMP fast rounds: greedy closure for re-activations, full maximal-
-    # message inference on the first round and at each quiescence point.
-    full_round = True
-
-    while active and rounds < max_rounds:
-        history.append(len(active))
-        rounds += 1
-        new_bits = m_bits.copy()
-        round_msgs: list[list[int]] = []
-        use_greedy = (
-            scheme == "mmp" and fast_rounds and not full_round
-            and isinstance(matcher, MLNMatcher) and matcher.collective
-        )
-        for k, rows in sorted(packed.rows_for(active).items()):
-            nb = packed.bins[k]
-            sel = (
-                nb.entity_mask[rows],
-                nb.coauthor[rows],
-                nb.sim_level[rows].astype(np.int8),
-                nb.pair_mask[rows],
-                uidx[k][rows],
-            )
-            gid_rows = nb.pair_gid[rows]
-            n_rows = len(rows)
-            padded = _pad_rows(list(sel), n_shards)
-            spec = _matcher_spec(matcher, k, Np)
-            if use_greedy:
-                spec = dataclasses.replace(spec, matcher_kind="mln_greedy")
-            fn = build_round_fn(spec, mesh, axes)
-            x, lab, bits = fn(*padded, jnp.asarray(m_bits))
-            dispatches += 1
-            x = np.asarray(x)[:n_rows]
-            lab = np.asarray(lab)[:n_rows]
-            new_bits |= np.asarray(bits)
-            evals += n_rows
-            if scheme == "mmp":
-                round_msgs.extend(_labels_to_messages(gid_rows, lab, m_plus))
-            if scheme == "nomp":
-                # no exchange: collect matches directly, never re-activate
-                for r in range(n_rows):
-                    sel_gids = gid_rows[r][x[r] & (gid_rows[r] >= 0)]
-                    m_plus = m_plus.union(sel_gids)
-
-        if scheme == "nomp":
-            break
-
-        newly = universe[new_bits & ~m_bits]
-        m_bits = new_bits
-        m_plus = m_plus.union(newly)
-
-        if scheme == "mmp":
-            for msg in round_msgs:
-                pool.add_message(msg)
-                emitted += 1
-            m_plus2, promoted = _promote(pool, gg, m_plus)
-            host_scans += 1
-            promoted_total += promoted
-            if promoted:
-                extra = m_plus2.difference(m_plus)
-                newly = np.unique(np.concatenate([newly, extra]))
-                m_plus = m_plus2
-                _set_bits(m_bits, universe, extra)
-
-        active = packed.neighborhoods_of_pairs(newly) if len(newly) else []
-
-        if scheme == "mmp" and fast_rounds:
-            if active:
-                full_round = False  # evidence to propagate: greedy rounds
-            elif use_greedy or not full_round:
-                # quiescent after greedy rounds: one full round to emit
-                # fresh maximal messages before declaring the fixpoint
-                full_round = True
-                active = list(range(packed.num_neighborhoods))
-
-    return EMResult(
-        matches=m_plus,
-        neighborhood_evals=evals,
-        rounds=rounds,
-        messages_emitted=emitted,
-        messages_promoted=promoted_total,
-        wall_time_s=time.perf_counter() - t0,
-        history=history,
-        dispatches=dispatches,
-        promote_host_scans=host_scans,
-    )

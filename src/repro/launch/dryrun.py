@@ -34,7 +34,7 @@ import traceback
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import NamedSharding
+from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ARCH_IDS, SHAPES, get_config, shape_applicable
@@ -231,67 +231,99 @@ def lower_cell(arch: str, shape_name: str, multi_pod: bool, *,
 
 
 def lower_em_cell(multi_pod: bool, *, k: int = 32, neighborhoods: int = 8192,
-                  universe: int = 1 << 20, matcher_kind: str = "mln"):
-    """Lower one SPMD message-passing round at production scale.
+                  pairs: int = 128, universe: int = 1 << 20,
+                  matcher_kind: str = "mln"):
+    """Lower the round engine's programs for one bin at production scale.
 
-    One round = batched MLN MAP inference on every active neighborhood
-    (sharded over all mesh axes) + the match-bitset all-reduce.  8192
-    neighborhoods of k=32 is a DBLP-BIG-scale round (§6.3).
+    These are the programs ``run_parallel`` dispatches for a bin: its
+    grounding (``_ground_bin_fn``, once per bin) and its full round
+    (``build_bin_round_fn``: batched MLN MAP inference on every active
+    neighborhood, sharded over all mesh axes, + the match-bitset
+    all-reduce).  Each neighborhood is staged on ``pairs`` compact slots,
+    the width of the DBLP cell's largest sub-bin; 8192 neighborhoods of
+    k=32 is a DBLP-BIG-scale round (§6.3).  FLOPs, bytes, collectives and
+    times are the two programs' sums; each memory field is the larger of
+    the two (they run one after the other).
     """
-    from repro.core import pairs as pairlib
     from repro.core.mln import PAPER_LEARNED
-    from repro.core.parallel import RoundSpec, build_round_fn
+    from repro.core.parallel import BinRoundSpec, _ground_bin_fn, build_bin_round_fn
 
-    mesh = _mesh(multi_pod)
+    prod = _mesh(multi_pod)
+    # auto axes, so that jit partitions the grounding over the batch
+    mesh = Mesh(prod.devices, prod.axis_names)
     axes = tuple(mesh.axis_names)
     n_chips = int(np.prod(mesh.devices.shape))
     B = max(neighborhoods, n_chips)
-    Pn = pairlib.num_pairs(k)
-    spec = RoundSpec(k=k, num_pairs=Pn, universe_size=universe,
-                     matcher_kind=matcher_kind, weights=PAPER_LEARNED)
-    fn = build_round_fn(spec, mesh, axes)
+    Pc = pairs
+    rows = NamedSharding(mesh, P(axes))
+    rep = NamedSharding(mesh, P())
 
-    def sds(shape, dt):
-        return jax.ShapeDtypeStruct(shape, dt)
+    def sds(shape, dt, sharding=rows):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=sharding)
 
-    args = (
+    ground = _ground_bin_fn(matcher_kind, PAPER_LEARNED)  # rules ignore the weights
+    ground_args = (
+        sds((B, k), jnp.int32),         # entity_ids
         sds((B, k), jnp.bool_),         # entity_mask
         sds((B, k, k), jnp.bool_),      # coauthor
-        sds((B, Pn), jnp.int8),         # sim_level
-        sds((B, Pn), jnp.bool_),        # pair_mask
-        sds((B, Pn), jnp.int32),        # uidx
-        sds((universe,), jnp.bool_),    # m_bits
+        sds((B, Pc), jnp.int8),         # sim_level
+        sds((B, Pc), jnp.bool_),        # pair_mask
+        sds((B, Pc), jnp.int16),        # slot_i
+        sds((B, Pc), jnp.int16),        # slot_j
     )
-    t0 = time.perf_counter()
-    lowered = fn.lower(*args)
-    t_lower = time.perf_counter() - t0
-    compiled = lowered.compile()
-    t_compile = time.perf_counter() - t0 - t_lower
-    mem = compiled.memory_analysis()
-    ana = hlo_analysis.analyze(
-        compiled.as_text(), n_devices=n_chips, pod_boundary=256
+    spec = BinRoundSpec(kind=matcher_kind, k=k, batch=B, num_pairs=Pc,
+                        universe_size=universe)
+    round_args = (
+        *[sds(a.shape, a.dtype) for a in jax.eval_shape(ground, *ground_args)],
+        sds((B, Pc), jnp.int32),        # uidx
+        sds((B, Pc), jnp.bool_),        # pair_mask
+        sds((B,), jnp.bool_),           # active rows
+        sds((universe,), jnp.bool_, rep),  # m_bits
     )
+    t_lower = t_compile = 0.0
+    mems, anas = [], []
+    for fn, args in ((ground, ground_args),
+                     (build_bin_round_fn(spec, mesh, axes), round_args)):
+        t0 = time.perf_counter()
+        lowered = fn.lower(*args)
+        t1 = time.perf_counter()
+        compiled = lowered.compile()
+        t_lower += t1 - t0
+        t_compile += time.perf_counter() - t1
+        mems.append(compiled.memory_analysis())
+        anas.append(hlo_analysis.analyze(
+            compiled.as_text(), n_devices=n_chips, pod_boundary=256
+        ))
+    ana = {key: sum(a[key] for a in anas) for key in anas[0]
+           if key != "collectives_by_kind"}
+    by_kind: dict[str, float] = {}
+    for a in anas:
+        for kind, nbytes in a["collectives_by_kind"].items():
+            by_kind[kind] = by_kind.get(kind, 0.0) + nbytes
     rec = {
-        "arch": f"em_round_{matcher_kind}", "shape": f"k{k}_B{B}",
+        "arch": f"em_round_{matcher_kind}", "shape": f"k{k}_Pc{Pc}_B{B}",
         "mesh": "2x16x16" if multi_pod else "16x16", "status": "ok",
         "kind": "em_round", "n_chips": n_chips,
         "params": 0, "active_params": 0, "tokens_per_step": B,
-        # useful work: one (P,P)@(P,P) entailment matmul + sweeps per nb
-        "model_flops": float(B * 2 * Pn * Pn * Pn),
+        # useful work: one (Pc,Pc)@(Pc,Pc) entailment matmul + sweeps per nb
+        "model_flops": float(B * 2 * Pc * Pc * Pc),
         "hlo_flops": ana["flops"],
         "hlo_bytes": ana["bytes"],
         "mem": {
-            "argument_bytes": int(mem.argument_size_in_bytes),
-            "output_bytes": int(mem.output_size_in_bytes),
-            "temp_bytes": int(mem.temp_size_in_bytes),
-            "alias_bytes": int(mem.alias_size_in_bytes),
-            "code_bytes": int(mem.generated_code_size_in_bytes),
+            key: max(int(getattr(m, field)) for m in mems)
+            for key, field in (
+                ("argument_bytes", "argument_size_in_bytes"),
+                ("output_bytes", "output_size_in_bytes"),
+                ("temp_bytes", "temp_size_in_bytes"),
+                ("alias_bytes", "alias_size_in_bytes"),
+                ("code_bytes", "generated_code_size_in_bytes"),
+            )
         },
         "collective_bytes": ana["collective_bytes"],
         "collective_wire_bytes": ana["collective_wire_bytes"],
         "collective_cross_pod_bytes": ana["collective_cross_pod_bytes"],
         "n_collectives": ana["n_collective_sites"],
-        "collectives_by_kind": ana["collectives_by_kind"],
+        "collectives_by_kind": by_kind,
         "unknown_whiles": ana["unknown_whiles"],
         "lower_s": round(t_lower, 2), "compile_s": round(t_compile, 2),
     }

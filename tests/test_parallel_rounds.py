@@ -7,12 +7,12 @@ The engine stages each neighborhood on its candidate pair slots
 resolves exactly as the full upper-triangle layout does for every
 device-capable matcher kind and scheme, on one device and sharded.
 
-The fused device-resident engine is checked three ways per scheme:
-bit-for-bit fixpoint equality against the sequential drivers, equality
-against the legacy per-round host loop (``fused=False``), and the
-device-residency accounting itself — the grounding is computed exactly
-once per bin per cover (ground-call counter) and the host dispatch
-count collapses from O(bins x rounds) to O(bins + quiescence points).
+The fused device-resident engine is checked two ways per scheme:
+bit-for-bit fixpoint equality against the sequential drivers, the one
+reference, and the device-residency accounting itself — the grounding
+is computed exactly once per bin per cover (ground-call counter) and
+the host dispatch count is O(bins + quiescence points), not
+O(bins x rounds).
 """
 
 from __future__ import annotations
@@ -66,14 +66,15 @@ def test_parallel_mmp_equals_sequential_fig1(fig1_packed, mln_pedagogical):
 
 
 @pytest.mark.parametrize(
-    "scheme,fast_rounds",
-    [("nomp", True), ("smp", True), ("mmp", True), ("mmp", False)],
+    "scheme,capacity",
+    [("nomp", None), ("smp", None), ("mmp", None), ("mmp", 1)],
 )
 def test_parallel_schemes_equal_sequential(hepth_state, mln_paper, scheme,
-                                           fast_rounds):
-    """All three schemes, fast_rounds on/off: the fused device engine,
-    the legacy per-round host loop, and the sequential driver agree
-    bit-for-bit on the fixpoint."""
+                                           capacity):
+    """All three schemes: the device engine and the sequential driver
+    agree bit-for-bit on the fixpoint.  ``capacity=1`` bounds the
+    grounding cache below the bin count (spill mode), where every round
+    is a full round: no fused greedy segment runs."""
     packed, gg = hepth_state
     if scheme == "nomp":
         seq = run_nomp(packed, mln_paper)
@@ -82,14 +83,13 @@ def test_parallel_schemes_equal_sequential(hepth_state, mln_paper, scheme,
     else:
         seq = run_mmp(packed, mln_paper, gg)
     par = run_parallel(
-        packed, mln_paper, gg, scheme=scheme, fast_rounds=fast_rounds
-    )
-    legacy = run_parallel(
-        packed, mln_paper, gg, scheme=scheme, fast_rounds=fast_rounds,
-        fused=False,
+        packed, mln_paper, gg, scheme=scheme,
+        gcache=GroundingCache(capacity=capacity),
     )
     assert par.matches.as_set() == seq.matches.as_set()
-    assert legacy.matches.as_set() == seq.matches.as_set()
+    if capacity is not None:
+        assert capacity < _staged_bins(packed)
+        assert par.full_rounds == par.rounds > 1
 
 
 def test_parallel_rules(hepth_state):
@@ -97,9 +97,7 @@ def test_parallel_rules(hepth_state):
     m = RulesMatcher()
     seq = run_smp(packed, m)
     par = run_parallel(packed, m, scheme="smp")
-    legacy = run_parallel(packed, m, scheme="smp", fused=False)
     assert seq.matches.as_set() == par.matches.as_set()
-    assert seq.matches.as_set() == legacy.matches.as_set()
 
 
 def test_grounding_once_per_bin_per_cover(hepth_state, mln_paper):
@@ -275,27 +273,25 @@ def test_fused_dispatch_counts(hepth_state, mln_paper):
     (greedy/rules) matcher's whole multi-round closure is ONE host
     dispatch; the collective MLN pays O(bins) per quiescence point plus
     one dispatch per greedy segment — O(bins + quiescence points), not
-    the legacy O(bins x rounds)."""
+    O(bins x rounds) — and reaches the sequential driver's fixpoint."""
     packed, gg = hepth_state
     n_bins = _staged_bins(packed)
 
     rules = run_parallel(packed, RulesMatcher(), scheme="smp")
     assert rules.dispatches == 1
-    rules_legacy = run_parallel(packed, RulesMatcher(), scheme="smp", fused=False)
-    assert rules_legacy.dispatches > rules.dispatches
 
     # collective SMP/MMP: full rounds only at the start and at greedy-
     # quiescence points; every re-activation round is inside a fused
     # greedy segment (one dispatch, however many rounds it runs) — the
     # dispatch count is O(bins x quiescence points + segments), not
     # O(bins x rounds).
+    seq = {"smp": run_smp(packed, mln_paper), "mmp": run_mmp(packed, mln_paper, gg)}
     for scheme in ("smp", "mmp"):
         res = run_parallel(packed, mln_paper, gg, scheme=scheme)
         assert 0 < res.full_rounds < res.rounds
         segments = res.rounds - res.full_rounds  # each is >= 1 round
         assert res.dispatches <= n_bins * res.full_rounds + segments
-        legacy = run_parallel(packed, mln_paper, gg, scheme=scheme, fused=False)
-        assert res.matches.as_set() == legacy.matches.as_set()
+        assert res.matches.as_set() == seq[scheme].matches.as_set()
 
 
 def test_lru_capacity_bounds_and_fixpoint(hepth_state, mln_paper):
@@ -380,14 +376,14 @@ def test_lru_lattice_fixpoint(mln_paper):
 def test_device_promotion_no_host_scans(hepth_state, mln_paper,
                                         fig1_packed, mln_pedagogical):
     """Step-7 promotion runs on device in the fused engine: zero host
-    coupling-COO walks, same fixpoint as the host-promoting legacy loop
-    and sequential driver (which both count their host scans)."""
+    coupling-COO walks, same fixpoint as the host-promoting sequential
+    driver (which counts its host scans)."""
     packed, gg = hepth_state
     res = run_parallel(packed, mln_paper, gg, scheme="mmp")
     assert res.promote_host_scans == 0
-    legacy = run_parallel(packed, mln_paper, gg, scheme="mmp", fused=False)
-    assert legacy.promote_host_scans > 0
-    assert res.matches.as_set() == legacy.matches.as_set()
+    seq = run_mmp(packed, mln_paper, gg)
+    assert seq.promote_host_scans > 0
+    assert res.matches.as_set() == seq.matches.as_set()
 
     # fig1 is the paper's promotion example: messages must actually be
     # promoted through the device path, not just trivially skipped
